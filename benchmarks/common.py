@@ -83,7 +83,3 @@ def _load_cached(cache: str, kw: Dict) -> P.SystemBundle | None:
     except Exception as e:
         print(f"# cache load failed ({e}); retraining", flush=True)
         return None
-
-
-def csv_row(name: str, seconds: float, derived: str) -> str:
-    return f"{name},{seconds*1e6:.0f},{derived}"

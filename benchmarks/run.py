@@ -3,6 +3,12 @@
 Prints ``name,us_per_call,derived`` CSV rows.  Scale via
 ``REPRO_BENCH_SCALE`` ∈ {"ci" (default), "full"}.  The roofline summary reads
 ``results/dryrun.jsonl`` if the multi-pod dry-run has been executed.
+
+Each figure runs in a child process (XLA:CPU's JIT code cache exhausts
+after many compilations in one process).  The parent never imports JAX, so
+on an accelerator only one process at a time holds the device; the first
+child trains and caches the shared system, later children reload it.  A
+figure that fails fails the run (non-zero exit) after the others finish.
 """
 from __future__ import annotations
 
@@ -16,34 +22,32 @@ MODULES = ("fig2_onboard", "fig3_redundancy", "fig4_connectivity",
            "fig12_multiscale")
 
 
+def csv_row(name: str, seconds: float, derived: str) -> str:
+    return f"{name},{seconds*1e6:.0f},{derived}"
+
+
 def run_one(name: str) -> None:
-    """Run a single figure module inline (invoked per-subprocess: XLA:CPU's
-    JIT code cache exhausts after many compilations in one process)."""
+    """Run a single figure module inline, in this child process."""
     import importlib
-    from benchmarks.common import get_bundle, csv_row
+    from benchmarks.common import get_bundle
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     bundle = get_bundle()
     mod = importlib.import_module(f"benchmarks.{name}")
-    t0 = time.time()
-    try:
-        for row in mod.run(bundle):
-            print(csv_row(*row), flush=True)
-    except Exception as e:  # pragma: no cover
-        print(csv_row(f"{name}_ERROR", time.time() - t0,
-                      f"{type(e).__name__}:{e}"), flush=True)
+    for row in mod.run(bundle):
+        print(csv_row(*row), flush=True)
 
 
-def main() -> None:
+def main() -> int:
     t_all = time.time()
     if len(sys.argv) > 2 and sys.argv[1] == "--module":
         run_one(sys.argv[2])
-        return
-    from benchmarks.common import get_bundle, csv_row
-
-    get_bundle()  # train + cache once; subprocesses reload from disk
+        return 0
     print("name,us_per_call,derived")
 
     env = dict(os.environ)
     env["PYTHONPATH"] = "src:."
+    failed = []
     for name in MODULES:
         t0 = time.time()
         proc = subprocess.run(
@@ -53,6 +57,7 @@ def main() -> None:
         if out:
             print(out, flush=True)
         if proc.returncode != 0:
+            failed.append(name)
             print(csv_row(f"{name}_SUBPROC_ERROR", time.time() - t0,
                           proc.stderr.strip()[-200:].replace("\n", " ")),
                   flush=True)
@@ -73,9 +78,11 @@ def main() -> None:
         print(csv_row("roofline_SKIPPED", 0.0,
                       "run repro.launch.dryrun first"), flush=True)
 
-    print(csv_row("total_wall", time.time() - t_all, "benchmarks complete"),
-          flush=True)
+    print(csv_row("total_wall", time.time() - t_all,
+                  f"failed: {','.join(failed)}" if failed
+                  else "benchmarks complete"), flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -180,7 +180,7 @@ def _legacy_admit(core: EngineCore, request: Request) -> int:
     images = jnp.asarray(np.asarray(request.image)[None])
     prompts = jnp.asarray(np.array([request.prompt], np.int32))
     ptok = core.ac.prompt_token(request.task, prompts)
-    logits, cache, idx = core._prefill_j(images, ptok,
+    logits, cache, idx = core._prefill_j(core._p, images, ptok,
                                          max_len=core._slot_max_len)
     core._slot_cache, core._slot_logits, core._slot_index = scatter(
         core._slot_cache, core._slot_logits, core._slot_index, cache, logits,
@@ -1358,70 +1358,21 @@ def _autotune_record(backend: str) -> Dict[str, object]:
 
 
 # ---------------------------------------------------------------------------
-# backend matrix
+# backends and workloads
 # ---------------------------------------------------------------------------
 
 #: cpu-interpret = the CPU backend with every kernel dispatch pinned to
 #: ``pallas_interpret``: the Pallas TPU kernel BODIES (int8 dequant
-#: included) execute in the serving loop instead of the jnp oracles — the
-#: closest this container gets to exercising the real kernels end-to-end.
+#: included) execute in the serving loop instead of the jnp oracles.  Each
+#: leg is its own process (a chip belongs to one process): pick the
+#: platform with JAX_PLATFORMS and the label with --backend.
 BACKENDS = ("cpu", "cpu-interpret", "gpu", "tpu")
-#: the interpret leg is orders of magnitude slower than compiled CPU, so
-#: the matrix runs it at smoke scale on the kernel-heavy workloads only
-INTERPRET_WORKLOADS = "impl,fanout,quantized"
 #: "sharded" is NOT in the default "all" set: it needs dp×tp devices
 #: (XLA_FLAGS host-platform slices on CPU) — run it via --mesh or an
 #: explicit --workloads sharded
 WORKLOADS = ("impl", "fanout", "spec", "chunked", "overload", "quantized",
              "sharded")
 DEFAULT_WORKLOADS = tuple(w for w in WORKLOADS if w != "sharded")
-
-
-def _backend_available(backend: str) -> bool:
-    """Probe a JAX platform in a THROWAWAY subprocess: the parent already
-    initialised its own backend, and a failed ``jax.devices()`` for an
-    absent platform would poison this process's runtime."""
-    import subprocess
-    env = dict(os.environ, JAX_PLATFORMS=backend.split("-")[0])
-    try:
-        r = subprocess.run([sys.executable, "-c",
-                            "import jax; jax.devices()"],
-                           env=env, capture_output=True, timeout=60)
-    except subprocess.TimeoutExpired:
-        # e.g. a tpu probe stuck waiting for libtpu on a CPU host
-        return False
-    return r.returncode == 0
-
-
-def _run_matrix(args, argv) -> int:
-    """Run one bench leg per available backend, sequentially, sharing
-    ``--out`` — each leg folds its predecessors into the backend-keyed
-    history, so the final file carries every backend's record.  Absent
-    backends are skipped with a notice, not an error (this container is
-    CPU-only; the gpu/tpu legs light up where the hardware exists)."""
-    import subprocess
-    base = [a for a in (argv if argv is not None else sys.argv[1:])
-            if a != "--matrix"]
-    rc = 0
-    # interpret before compiled cpu, accelerators last: each leg folds its
-    # predecessor into history, so the file's TOP-LEVEL record ends up being
-    # the most production-like backend that actually ran
-    for backend in ("cpu-interpret", "cpu", "gpu", "tpu"):
-        if not _backend_available(backend):
-            print(f"[matrix] {backend}: backend unavailable, skipped",
-                  flush=True)
-            continue
-        leg = base + ["--backend", backend]
-        if backend == "cpu-interpret" and "--workloads" not in base:
-            leg += ["--workloads", INTERPRET_WORKLOADS]
-            if "--smoke" not in leg:
-                leg.append("--smoke")
-        env = dict(os.environ, JAX_PLATFORMS=backend.split("-")[0])
-        print(f"[matrix] {backend}: {' '.join(leg)}", flush=True)
-        r = subprocess.run([sys.executable, os.path.abspath(__file__)]
-                           + leg, env=env)
-        rc = rc or r.returncode
-    return rc
 
 
 def main(argv=None) -> int:
@@ -1491,12 +1442,9 @@ def main(argv=None) -> int:
                     default="auto",
                     help="backend label for this leg; cpu-interpret pins "
                          "kernel dispatch to pallas_interpret (kernel "
-                         "bodies execute on CPU).  The JAX platform itself "
+                         "bodies execute on CPU); gpu/tpu fail unless JAX "
+                         "runs on that platform.  The JAX platform itself "
                          "is chosen via JAX_PLATFORMS before process start")
-    ap.add_argument("--matrix", action="store_true",
-                    help="run one leg per available backend (cpu / "
-                         "cpu-interpret / gpu / tpu), sequentially, folding "
-                         "all records into one backend-keyed history")
     ap.add_argument("--mesh", default=None, metavar="dp2,tp2",
                     help="device-mesh shape for the sharded workload "
                          "(launch.mesh.parse_mesh_shape syntax); implies "
@@ -1521,14 +1469,17 @@ def main(argv=None) -> int:
 
     if args.trend:
         return _print_trend(args.out)
-    if args.matrix:
-        return _run_matrix(args, argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     backend = (jax.default_backend() if args.backend == "auto"
                else args.backend)
+    platform = backend.split("-")[0]
+    if jax.default_backend() != platform:
+        # never measure one platform under another's name
+        raise SystemExit(f"--backend {backend} needs JAX on {platform}, "
+                         f"found {jax.default_backend()}")
     if backend == "cpu-interpret":
-        if jax.default_backend() != "cpu":
-            raise SystemExit("cpu-interpret needs JAX_PLATFORMS=cpu")
         from repro.kernels import ops
         ops.set_default_impl("pallas_interpret")
 

@@ -17,9 +17,11 @@ from repro.core import pipeline as P
 from repro.core import preprocess as PP
 from repro.core import region_attention as RA
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     bundle = P.build_system(scale="small", n_train=160, n_test=32,
                             proxy_steps=120, conf_steps=80, seed=0,
                             tasks=("det",))

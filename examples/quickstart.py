@@ -15,9 +15,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import numpy as np
 
 from repro.core import pipeline as P
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     print("== training tiers + confidence network (tiny scale) ==")
     bundle = P.build_system(scale="small", n_train=192, n_test=64,
                             proxy_steps=150, conf_steps=150, seed=0,

@@ -31,6 +31,7 @@ import argparse
 import numpy as np
 
 from repro.core import pipeline as P
+from repro.launch.compile_cache import enable_compile_cache
 from repro.network.orbit import ContactPlan
 from repro.serving import (CascadeServer, EngineConfig, EngineCore,
                            EngineCoreConfig, InferenceEngine, Request)
@@ -38,6 +39,7 @@ from repro.serving.engine_core import shared_core
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=48)
     ap.add_argument("--contact-fraction", type=float, default=1.0,

@@ -24,6 +24,7 @@ from repro.core import eo_adapter as EO
 from repro.core import pipeline as P
 from repro.core.cascade import TierModel
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import checkpoint as CK
 from repro.train import compression as GC
 from repro.train import optimizer as O
@@ -84,6 +85,7 @@ def train_tier(name, cfg, adapter_cfg, train_data, steps, lr, ckpt_dir,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=["small", "example"], default="small")
     ap.add_argument("--steps", type=int, default=200)

@@ -19,13 +19,20 @@ import jax.numpy as jnp
 
 
 def _avg_pool(regions: jax.Array, f: int) -> jax.Array:
-    """(B, R, h, w, C) average-pool by factor f then nearest-upsample back."""
+    """(B, R, h, w, C) average-pool by factor f then nearest-upsample back.
+    A side that f does not divide (28-pixel Qwen2-VL regions at f = 8) pools
+    its last, partial cells over the pixels they actually hold."""
     if f == 1:
         return regions
     b, r, h, w, c = regions.shape
-    x = regions.reshape(b, r, h // f, f, w // f, f, c).mean(axis=(3, 5))
+    hp, wp = -(-h // f) * f, -(-w // f) * f
+    pad = ((0, hp - h), (0, wp - w))
+    x = jnp.pad(regions, ((0, 0), (0, 0)) + pad + ((0, 0),))
+    n = jnp.pad(jnp.ones((h, w), regions.dtype), pad)
+    x = (x.reshape(b, r, hp // f, f, wp // f, f, c).sum(axis=(3, 5))
+         / n.reshape(hp // f, f, wp // f, f).sum(axis=(1, 3))[..., None])
     x = jnp.repeat(jnp.repeat(x, f, axis=2), f, axis=3)
-    return x
+    return x[:, :, :h, :w]
 
 
 def scale_factor(scores: jax.Array, alpha: float, beta: float) -> jax.Array:
@@ -43,7 +50,8 @@ def multiscale_filter(regions: jax.Array, scores: jax.Array, *,
     """regions: (B, R, h, w, C); scores: (B, R) normalised K(x^r).
 
     Returns (filtered regions, tx_bytes (B,), meta).  ``tx_bytes`` counts
-    h·w·C/ c² per kept region (c = selected pooling level), zero if dropped.
+    the pooled cells sent per kept region, ⌈h/c⌉·⌈w/c⌉·C (c = selected
+    pooling level; partial edge cells are sent too), zero if dropped.
     """
     b, r, h, w, ch = regions.shape
     c = scale_factor(scores, alpha, beta)                     # (B, R)
@@ -62,7 +70,9 @@ def multiscale_filter(regions: jax.Array, scores: jax.Array, *,
     out = jnp.where(discard[..., None, None, None], 0.0, sel)
 
     level_vals = jnp.take(lv, li)
-    px = (h * w * ch) / (level_vals ** 2)
+    cells = jnp.asarray([-(-h // f) * -(-w // f) * ch for f in levels],
+                        jnp.float32)
+    px = jnp.take(cells, li)
     tx_bytes = jnp.where(discard, 0.0, px * bytes_per_px).sum(-1)  # (B,)
     full_bytes = float(r * h * w * ch * bytes_per_px)
     meta = {

@@ -25,7 +25,6 @@ from typing import Any, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import compat
 
 Params = Any
 
@@ -75,7 +74,7 @@ def tp_mlp_all_reduce(x: jax.Array) -> jax.Array:
 
 def reduce_scatter_grads(grads: Params, axis: str) -> Params:
     """psum_scatter each leaf over ``axis`` (leading dim must divide)."""
-    size = compat.axis_size(axis)
+    size = jax.lax.axis_size(axis)
 
     def one(g):
         if g.ndim == 0 or g.shape[0] % size != 0:

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed import compat
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, x_micro: jax.Array,
@@ -72,8 +71,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro: jax.Array,
         return last
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = compat.shard_map(per_stage, mesh=mesh,
-                          in_specs=(spec_p, P()), out_specs=P())
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=(spec_p, P()),
+                       out_specs=P(), check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -20,7 +20,7 @@ axis that folds into data parallelism):
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -301,6 +301,26 @@ def serving_param_specs(plan: TPServingPlan, params_shape: Params) -> Params:
         return P(*([None] * nd))
 
     return jax.tree_util.tree_map_with_path(rule, params_shape)
+
+
+def adapter_param_specs(plan: TPServingPlan, params_shape: Params) -> Params:
+    """Spec tree for a whole EO-adapter param tree (``EO.init_adapter``):
+    the backbone per :func:`serving_param_specs`, everything else (the
+    patch projection) replicated."""
+    return {k: (serving_param_specs(plan, v) if k == "backbone"
+                else jax.tree.map(lambda x: P(), v))
+            for k, v in params_shape.items()}
+
+
+def init_sharded(init_fn: Callable[..., Params], cfg: ArchConfig,
+                 mesh: Mesh, *args) -> Params:
+    """``init_fn(*args)`` — an EO-adapter param tree for backbone ``cfg``,
+    e.g. ``EO.init_adapter`` — generated straight into the serving plan's
+    shardings on ``mesh``: each device makes only its own shards, so a model
+    that no single device holds is never materialised on one."""
+    specs = adapter_param_specs(tp_serving_plan(cfg, mesh),
+                                jax.eval_shape(init_fn, *args))
+    return jax.jit(init_fn, out_shardings=named(mesh, specs))(*args)
 
 
 def paged_kv_leaf_spec(nd: int, sharded: bool) -> P:

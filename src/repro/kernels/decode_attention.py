@@ -92,7 +92,7 @@ def largest_divisor_leq(n: int, cap: int) -> int:
 
 def _kv_block_update(q, k_ref, v_ref, ks_ref, vs_ref, ikv, cache_len, t0,
                      acc_ref, m_ref, l_ref, *, scale, window, softcap,
-                     kv_blk, q_len, group, native_dot):
+                     kv_blk, q_len, group, native_dot, kv_total=None):
     """One KV block's online-softmax update (shared by the decode and
     prefill kernel bodies, and by every ``fan`` sub-block within a grid
     step).  ``t0`` is the first chunk-token index covered by this query
@@ -104,9 +104,18 @@ def _kv_block_update(q, k_ref, v_ref, ks_ref, vs_ref, ikv, cache_len, t0,
     ``native_dot``, the widening-dot path for fp8 pools where the stored
     block feeds ``dot_general`` directly and the scale commutes out of the
     contraction: ``dot(q, k·s)[r, c] = dot(q, k)[r, c] · s[c]`` for QK, and
-    ``dot(p, v·s) = dot(p·sᵀ, v)`` for PV."""
+    ``dot(p, v·s) = dot(p·sᵀ, v)`` for PV.
+
+    ``kv_total`` (dense kernel only) is the KV axis length when the last
+    block runs past it: rows beyond it hold garbage (NaN in interpret
+    mode), so they are zeroed before the PV dot — a masked score alone
+    gives p == 0, and 0 · NaN is still NaN."""
     k = k_ref[0, 0]
     v = v_ref[0, 0]
+    if kv_total is not None:
+        vrow = ikv * kv_blk + jax.lax.broadcasted_iota(jnp.int32,
+                                                       (v.shape[0], 1), 0)
+        v = jnp.where(vrow < kv_total, v, jnp.zeros_like(v))
     if ks_ref is not None and native_dot:
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -173,7 +182,7 @@ def _parse_kv_refs(rest, fan):
 def _decode_kernel(len_ref, q_ref, *rest, scale: float, window: int,
                    softcap: Optional[float], kv_blk: int, n_kv: int,
                    q_len: int = 1, group: int = 0, fan: int = 1,
-                   native_dot: bool = False):
+                   native_dot: bool = False, kv_total=None):
     """Decode / multi-token verify body for one (batch row, KV head) and one
     KV grid step.  ``n_kv`` counts GRID steps along the KV axis; each step
     reduces ``fan`` consecutive logical blocks (sub-block ``f`` covers
@@ -208,7 +217,7 @@ def _decode_kernel(len_ref, q_ref, *rest, scale: float, window: int,
                              cache_len, 0, acc_ref, m_ref, l_ref,
                              scale=scale, window=window, softcap=softcap,
                              kv_blk=kv_blk, q_len=q_len, group=group,
-                             native_dot=native_dot)
+                             native_dot=native_dot, kv_total=kv_total)
 
     @pl.when(ig == n_kv - 1)
     def _finalize():
@@ -227,20 +236,24 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     the q_len chunk tokens) → (B, KH, q_len·group, hd).  ``q_len > 1``
     scores a multi-token chunk causally within the chunk (speculative
     verify); ``q_len == 1`` is plain decode.  ``kv_blk`` is the tunable KV
-    tile (``kernels/autotune.py`` sweeps it per backend)."""
+    tile (``kernels/autotune.py`` sweeps it per backend).
+
+    A KV tile is the whole axis or a multiple of 8 rows (the TPU sublane
+    tile).  When ``S`` is not a multiple of the tile the grid is
+    ``cdiv(S, kv_blk)`` and the tail block's rows past ``S`` are masked —
+    capacities like N_r + 1 + answer + γ are rarely round."""
     b, kh, rows, hd = q.shape
     s = k.shape[2]
     assert rows % q_len == 0
     group = rows // q_len
     scale = scale if scale is not None else hd ** -0.5
-    kv_blk = min(kv_blk, s)
-    if s % kv_blk != 0:
-        kv_blk = largest_divisor_leq(s, kv_blk)
-    n_kv = s // kv_blk
+    kv_blk = s if s <= kv_blk else max(kv_blk - kv_blk % 8, 8)
+    n_kv = pl.cdiv(s, kv_blk)
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, softcap=softcap,
-        kv_blk=kv_blk, n_kv=n_kv, q_len=q_len, group=group)
+        kv_blk=kv_blk, n_kv=n_kv, q_len=q_len, group=group,
+        kv_total=s if s % kv_blk else None)
 
     # list-built (not inline) so the spec count stays dynamic: the kernel
     # body takes the KV refs as a vararg tail the static arity check
@@ -486,6 +499,10 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pool: jax.Array,
     scale = scale if scale is not None else hd ** -0.5
     if q_len % q_blk != 0:
         q_blk = largest_divisor_leq(q_len, q_blk)
+    if (q_blk * group) % 8 and q_blk != q_len:
+        # a query sub-block must span a multiple of 8 rows (the TPU sublane
+        # tile) unless it is the whole chunk
+        q_blk = q_len
     n_q = q_len // q_blk
     sub_rows = q_blk * group
     fan = _resolve_fan(fan, n_blocks)

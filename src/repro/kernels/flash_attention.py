@@ -8,9 +8,12 @@ materialised ``group`` times in HBM or VMEM.
 Layout: q (B, H, Sq, hd); k, v (B, K, Skv, hd).  Grid (B, H, n_q, n_kv) with
 the KV axis innermost; running max / denominator / accumulator live in VMEM
 scratch persisted across the innermost grid dimension (standard TPU flash
-pattern).  MXU alignment: q/kv block sizes are multiples of 128 whenever the
-sequence is, and head_dim is zero-padded to a multiple of 128 by the wrapper
-in ``ops.py``.
+pattern).  MXU alignment: q/kv blocks are 128 rows (the whole sequence when
+it is shorter).  A sequence that is not a multiple of the block (a
+1024-patch prefix plus its prompt token is 1025) runs on a ``cdiv`` grid:
+the last block reads past the end, its out-of-range KV columns are masked
+and its out-of-range V rows zeroed, and the out-of-range query rows it
+computes are never written back.
 """
 from __future__ import annotations
 
@@ -28,7 +31,7 @@ NEG_INF = -1e30
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, window: int,
                   softcap: Optional[float], q_blk: int, kv_blk: int,
-                  n_kv: int):
+                  n_kv: int, skv: int):
     iq = pl.program_id(2)
     ikv = pl.program_id(3)
 
@@ -53,13 +56,19 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         q = q_ref[0, 0].astype(jnp.float32)          # (q_blk, hd)
         k = k_ref[0, 0].astype(jnp.float32)          # (kv_blk, hd)
         v = v_ref[0, 0].astype(jnp.float32)
+        if skv % kv_blk:
+            # ragged tail block: rows past the array end hold garbage (NaN
+            # in interpret mode); zero them so p == 0 cannot meet a NaN
+            vrow = ikv * kv_blk + jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0)
+            v = jnp.where(vrow < skv, v, 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
         rows = iq * q_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         cols = ikv * kv_blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = jnp.ones_like(s, dtype=jnp.bool_)
+        mask = cols < skv
         if causal:
             mask &= cols <= rows
         if window > 0:
@@ -92,14 +101,15 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kh, skv = k.shape[1], k.shape[2]
     group = h // kh
     scale = scale if scale is not None else hd ** -0.5
-    q_blk = min(q_blk, sq)
-    kv_blk = min(kv_blk, skv)
-    assert sq % q_blk == 0 and skv % kv_blk == 0
-    n_q, n_kv = sq // q_blk, skv // kv_blk
+    # a block is either the whole axis or a multiple of 8 rows (the TPU
+    # sublane tile); a ragged tail runs on a cdiv grid (module docstring)
+    q_blk = sq if sq <= q_blk else q_blk - q_blk % 8
+    kv_blk = skv if skv <= kv_blk else kv_blk - kv_blk % 8
+    n_q, n_kv = pl.cdiv(sq, q_blk), pl.cdiv(skv, kv_blk)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
-        softcap=softcap, q_blk=q_blk, kv_blk=kv_blk, n_kv=n_kv)
+        softcap=softcap, q_blk=q_blk, kv_blk=kv_blk, n_kv=n_kv, skv=skv)
 
     return pl.pallas_call(
         kernel,

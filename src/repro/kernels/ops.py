@@ -35,7 +35,9 @@ def set_default_impl(impl: Optional[str]) -> Optional[str]:
     lowered HLO matches the TPU kernel's work profile).  Returns the
     previous override so callers can scope it (e.g. pin "ref" across a
     training phase — the serving kernels are inference-only and define no
-    autodiff rules)."""
+    autodiff rules).  The override is read at trace time, and jit caches
+    traces per function object: after switching, jit a new function (a
+    fresh closure), or the old trace and its kernels are replayed."""
     global _DEFAULT_OVERRIDE
     prev = _DEFAULT_OVERRIDE
     _DEFAULT_OVERRIDE = impl

@@ -37,7 +37,7 @@ from repro.kernels import ops as KOPS
 # Lower with the flash-structured attention reference so the compiled
 # FLOP/byte profile matches the TPU Pallas kernels (no S² score buffers).
 KOPS.set_default_impl("flash_structured")
-from repro.distributed import compat, hlo_analysis, hlo_parser
+from repro.distributed import hlo_analysis, hlo_parser
 from repro.distributed import sharding as SH
 from repro.launch import specs as SP
 from repro.launch.mesh import make_production_mesh
@@ -78,10 +78,8 @@ def build_lowerable(cfg, shape: ShapeSpec, mesh, *,
         if batch_spec_fn:
             b_specs = batch_spec_fn(cfg, mesh, shape, b_specs)
         fn = jax.jit(step,
-                     in_shardings=compat.shardings(
-                         mesh, (p_specs, o_specs, b_specs)),
-                     out_shardings=compat.shardings(
-                         mesh, (p_specs, o_specs, None)),
+                     in_shardings=(p_specs, o_specs, b_specs),
+                     out_shardings=(p_specs, o_specs, None),
                      donate_argnums=(0, 1))
         return fn, (p_shape, opt_shape, ins["batch"])
 
@@ -99,8 +97,8 @@ def build_lowerable(cfg, shape: ShapeSpec, mesh, *,
         if batch_spec_fn:
             b_specs = batch_spec_fn(cfg, mesh, shape, b_specs)
         fn = jax.jit(prefill_fn,
-                     in_shardings=compat.shardings(mesh, (p_specs, b_specs)),
-                     out_shardings=compat.shardings(mesh, (None, c_specs)))
+                     in_shardings=(p_specs, b_specs),
+                     out_shardings=(None, c_specs))
         return fn, (p_shape, ins["inputs"])
 
     if shape.kind == "decode":
@@ -114,10 +112,9 @@ def build_lowerable(cfg, shape: ShapeSpec, mesh, *,
         if batch_spec_fn:
             b_specs = batch_spec_fn(cfg, mesh, shape, b_specs)
         fn = jax.jit(decode_fn,
-                     in_shardings=compat.shardings(
-                         mesh, (p_specs, c_specs, b_specs,
-                                jax.sharding.PartitionSpec())),
-                     out_shardings=compat.shardings(mesh, (None, c_specs)),
+                     in_shardings=(p_specs, c_specs, b_specs,
+                                   jax.sharding.PartitionSpec()),
+                     out_shardings=(None, c_specs),
                      donate_argnums=(1,))
         return fn, (p_shape, ins["cache"], ins["inputs"], ins["index"])
 
@@ -136,7 +133,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
         "n_devices": int(mesh.size),
     }
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, arg_specs = build_lowerable(cfg, shape, mesh,
                                         microbatches=microbatches,
                                         remat=remat, **variant)
@@ -157,7 +154,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
     except Exception as e:  # pragma: no cover
         rec["memory"] = {"error": str(e)}
     try:
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         rec["cost"] = {k: float(v) for k, v in cost.items()
                        if isinstance(v, (int, float))}
     except Exception as e:  # pragma: no cover

@@ -67,8 +67,10 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> Params:
     blocks = []
     for pos, spec in enumerate(cfg.block_pattern):
         pos_keys = jax.random.split(jax.random.fold_in(kb, pos), cfg.n_super)
-        per_super = [_init_block(k, cfg, spec) for k in pos_keys]
-        blocks.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_super))
+        # one vmapped init per pattern position (the same values as a loop
+        # over super-blocks, but O(1) program size in the depth when jitted)
+        blocks.append(jax.vmap(lambda k, spec=spec: _init_block(k, cfg, spec))(
+            pos_keys))
     return {
         "embed": frontends.init_embed(ke, cfg),
         "blocks": tuple(blocks),

@@ -59,7 +59,6 @@ from repro.analysis.compile_guard import CompileGuard
 from repro.configs.base import ATTN, HYBRID
 from repro.core import eo_adapter as EO
 from repro.distributed import collectives as CO
-from repro.distributed import compat
 from repro.distributed import sharding as SH
 from repro.kernels import kv_quant
 from repro.models import transformer as T
@@ -342,39 +341,97 @@ class EngineCore:
                     "uncommitted placements across the admit path (and "
                     "head-sharding has nothing to shard in an SSM state)")
             plan = SH.tp_serving_plan(cfg, self.mesh)
+            # weights go to the plan's shardings once (a no-op when they
+            # were initialised there, see ``SH.init_sharded``)
+            self._p_specs = SH.adapter_param_specs(
+                plan, jax.eval_shape(lambda: params))
+            params = jax.device_put(params, SH.named(self.mesh,
+                                                     self._p_specs))
         self._tp_plan = plan
         mesh = self.mesh
-        bb_host = params["backbone"]
+        # Every jitted family takes the weights as an OPERAND, never as a
+        # closure: jit embeds closed-over arrays into the program as
+        # constants, which at published widths means gigabytes of HLO and
+        # a second copy of the weights on the device.
+        #: the adapter param tree (backbone + patch projection) and the
+        #: backbone alone — the leading operand of every jitted call
+        self._p = params
+        self._bb = params["backbone"]
+        self._dp = None
         # config the step bodies run the model with: per-device head counts
         # under shard_map (head_dim pinned so RoPE is unchanged), identical
         # to ``cfg`` on a single device
         mcfg = plan.cfg_local if plan is not None else cfg
 
-        def _encode(images, ptok):
-            rf = EO.encode_regions(params, ac, images)
-            tf = EO.encode_text(params, cfg, ptok)
+        if mesh is not None:
+            # -- sharded jit family -----------------------------------------
+            # Every jit that runs a Pallas kernel runs under ONE shard_map
+            # over the ("data"=1, "model"=tp) mesh (the TPU lowering refuses
+            # a Mosaic kernel under jit's automatic partitioning): q/k/v/o
+            # projections and every KV cache are head-sharded per the
+            # serving plan, all other operands replicated.  The tp_context
+            # arms the all-reduce hooks in models/layers.py at trace time.
+            rep = P()
+            self._rep_sharding = SH.named(mesh, rep)
+            self._bb_specs = self._p_specs["backbone"]
+            # a dense KV cache (batch path, regions-only prefill),
+            # head-sharded the same way as the paged pools
+            dense_specs = T.map_cache_kinds(
+                cfg, [jax.eval_shape(lambda: T.init_cache(cfg, 1, 1))],
+                kv=lambda t: jax.tree.map(
+                    lambda x: SH.paged_kv_leaf_spec(len(x.shape),
+                                                    plan.attn), t),
+                state=lambda t: jax.tree.map(lambda x: P(), t))
+
+            def shard_jit(fn, in_specs, out_specs, static=()):
+                """``jit(shard_map(fn))`` keeping the ``static`` kwargs
+                static (the shard_map is staged per static value inside
+                jit's trace cache, exactly one compile per value)."""
+                @functools.partial(jax.jit, static_argnames=static)
+                def call(*args, **kw):
+                    def body(*ops):
+                        with CO.tp_context("model", attn=plan.attn,
+                                           mlp=plan.mlp):
+                            return fn(*ops, **kw)
+                    return jax.shard_map(
+                        body, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)(*args)
+                return call
+
+        def _encode(p, images, ptok):
+            rf = EO.encode_regions(p, ac, images)
+            tf = EO.encode_text(p, cfg, ptok)
             vis = rf.astype(jnp.float32).mean(axis=1)
             return rf, tf, vis
 
-        def _prefill(images, ptok, *, max_len):
-            return EO.prefill_tokens(params, cfg, ac, images, ptok, max_len)
+        def _prefill(p, images, ptok, *, max_len):
+            return EO.prefill_tokens(p, mcfg, ac, images, ptok, max_len)
 
-        def _decode_chunk(cache, logits, idx, *, n_tokens, answer_vocab):
-            return EO.decode_chunk(params, cfg, cache, logits, idx,
+        def _decode_chunk(p, cache, logits, idx, *, n_tokens, answer_vocab):
+            return EO.decode_chunk(p, mcfg, cache, logits, idx,
                                    n_tokens, answer_vocab)
 
         self._encode_j = jax.jit(_encode)
-        self._prefill_j = jax.jit(_prefill, static_argnames=("max_len",))
-        self._decode_chunk_j = jax.jit(
-            _decode_chunk, static_argnames=("n_tokens", "answer_vocab"))
+        if mesh is None:
+            self._prefill_j = jax.jit(_prefill, static_argnames=("max_len",))
+            self._decode_chunk_j = jax.jit(
+                _decode_chunk, static_argnames=("n_tokens", "answer_vocab"))
+        else:
+            self._prefill_j = shard_jit(
+                _prefill, (self._p_specs, rep, rep), (rep, dense_specs, rep),
+                ("max_len",))
+            self._decode_chunk_j = shard_jit(
+                _decode_chunk, (self._p_specs, dense_specs, rep, rep),
+                (rep, rep, dense_specs, rep, rep),
+                ("n_tokens", "answer_vocab"))
         self._token_feats_j = jax.jit(
-            lambda toks: EO.token_features(params, toks))
+            lambda p, toks: EO.token_features(p, toks))
         # scene-keyed encode memo for the serve path (bounded LRU)
         self._encode_cache: "OrderedDict[Any, Tuple]" = OrderedDict()
         self._encode_cache_cap = 32
 
         # -- slot-path compiled functions (shapes fixed at construction) ----
-        def _slot_step(slot_logits, slot_cache, slot_index, active,
+        def _slot_step(bb, slot_logits, slot_cache, slot_index, active,
                        *, answer_vocab):
             """All-slot decode step: ONE batched ``T.decode_step`` over the
             whole slot table with a (slots,) ragged index vector.  Per-row
@@ -384,8 +441,7 @@ class EngineCore:
             a_logits = slot_logits[:, :answer_vocab]
             toks = jnp.argmax(a_logits, axis=-1).astype(jnp.int32)
             new_logits, new_cache = T.decode_step(
-                params["backbone"], cfg, slot_cache, {"tokens": toks[:, None]},
-                slot_index)
+                bb, cfg, slot_cache, {"tokens": toks[:, None]}, slot_index)
             new_index = jnp.where(active, slot_index + 1, slot_index)
             return toks, new_logits, new_cache, new_index
 
@@ -395,10 +451,8 @@ class EngineCore:
             KV write/read resolve through the block table.  Inactive slots'
             block-table rows point at the trash page, so their garbage write
             can never land in a page another sequence owns.  ``bb`` is the
-            backbone param tree — an explicit operand (not a closure) so the
-            sharded engine can feed per-device weight shards through
-            ``shard_map``; single-device engines partial-bind the host copy,
-            which jit treats as the same closure constant as before."""
+            backbone param tree (the sharded engine feeds per-device weight
+            shards through ``shard_map``)."""
             a_logits = slot_logits[:, :answer_vocab]
             toks = jnp.argmax(a_logits, axis=-1).astype(jnp.int32)
             new_logits, new_cache = T.decode_step(
@@ -407,20 +461,20 @@ class EngineCore:
             new_index = jnp.where(active, slot_index + 1, slot_index)
             return toks, new_logits, new_cache, new_index
 
-        def _one_step(tok, cache_s, idx):
-            """Advance ONE slot by one token (legacy vmap oracle).
-
-            ``cache_s``: this slot's cache slice (batch axis stripped)."""
-            c1 = jax.tree.map(lambda x: x[:, None], cache_s)
-            logits, new_c = T.decode_step(params["backbone"], cfg, c1,
-                                          {"tokens": tok[None, None]}, idx)
-            return logits[0], jax.tree.map(lambda x: x[:, 0], new_c)
-
-        def _slot_step_vmap(slot_logits, slot_cache, slot_index, active,
+        def _slot_step_vmap(bb, slot_logits, slot_cache, slot_index, active,
                             *, answer_vocab):
             """Pre-batching per-slot step: vmap of a batch-1 decode over the
             stacked table.  Kept as the token-for-token equivalence oracle
             for tests and the before/after benchmark baseline."""
+            def _one_step(tok, cache_s, idx):
+                """Advance ONE slot by one token; ``cache_s`` is this slot's
+                cache slice (batch axis stripped)."""
+                c1 = jax.tree.map(lambda x: x[:, None], cache_s)
+                logits, new_c = T.decode_step(bb, cfg, c1,
+                                              {"tokens": tok[None, None]},
+                                              idx)
+                return logits[0], jax.tree.map(lambda x: x[:, 0], new_c)
+
             a_logits = slot_logits[:, :answer_vocab]
             toks = jnp.argmax(a_logits, axis=-1).astype(jnp.int32)
             new_logits, new_cache = jax.vmap(
@@ -448,19 +502,13 @@ class EngineCore:
         elif self.cache_impl == "paged":
             if mesh is None:
                 self._slot_step_j = jax.jit(
-                    functools.partial(_slot_step_paged, bb_host),
-                    static_argnames=("answer_vocab",))
+                    _slot_step_paged, static_argnames=("answer_vocab",))
             # mesh: jitted under shard_map in the paged section below, once
             # the pool shape (and hence the cache partition specs) exists
         else:
             self._slot_step_j = jax.jit(_slot_step,
                                         static_argnames=("answer_vocab",))
         self._slot_scatter_many_j = jax.jit(_slot_scatter_many)
-        #: positional prefix for the model-calling jitted families: the
-        #: sharded engine passes the device-put backbone as an explicit
-        #: shard_map operand; single-device engines keep it partial-bound
-        #: (empty prefix — call sites and HLO stay byte-identical)
-        self._bb_arg: Tuple = ()
 
         # -- paged-cache machinery ------------------------------------------
         if self.cache_impl == "paged":
@@ -518,17 +566,18 @@ class EngineCore:
                                   TRASH_PAGE, np.int32)
             self._bt_dev = None
 
-            def _prefill_prefix(images):
+            def _prefill_prefix(p, images):
                 """Regions-only prefill: the shared prefix of every query
                 over one scene (KV capacity exactly N_r → reshapes straight
                 into whole pages; final recurrent state = the snapshot a
-                prompt-suffix admission resumes from)."""
-                _, cache, _ = EO.prefill_regions(params, cfg, ac, images,
+                prompt-suffix admission resumes from).  On a mesh it runs
+                tensor-parallel like the steps, so each device computes
+                exactly the KV-head block its pool shard keeps."""
+                _, cache, _ = EO.prefill_regions(p, mcfg, ac, images,
                                                  n_regions)
                 return cache
 
             n_shared = self._n_shared_pages
-            tp_heads = plan.tp if (plan is not None and plan.attn) else 1
 
             def _prefix_scatter(slot_cache, prefix_cache, pages):
                 """Write K scenes' region KV into their shared pages.
@@ -540,20 +589,6 @@ class EngineCore:
                         resh = pref_leaf.reshape(
                             (ns, kb * n_shared, ps) + pref_leaf.shape[3:])
                         return pool_leaf.at[:, pages].set(resh)
-                    if tp_heads > 1:
-                        # the dense prefix prefill runs replicated (full
-                        # heads on every device); each device keeps only its
-                        # contiguous KV-head block for its pool shard.
-                        # Sliced BEFORE quantization — int8 scales are
-                        # per-(token, head), so slicing commutes exactly.
-                        r = jax.lax.axis_index("model")
-
-                        def shard_heads(x):
-                            h = x.shape[3] // tp_heads
-                            return jax.lax.dynamic_slice_in_dim(
-                                x, r * h, h, axis=3)
-
-                        pref = jax.tree.map(shard_heads, pref)
                     if "k_scale" in pool:
                         # quantized pool, exact dense prefix cache: quantize
                         # at scatter time so the shared pages carry the same
@@ -618,28 +653,11 @@ class EngineCore:
                                slot_index).astype(slot_index.dtype)
                 return sl, cache3, si
 
-            # the dense regions-only prefill always runs replicated: its
-            # output is uncommitted and flows into the sharded scatter,
-            # which keeps only the local head block per device
-            self._prefill_prefix_j = jax.jit(_prefill_prefix)
             if mesh is None:
+                self._prefill_prefix_j = jax.jit(_prefill_prefix)
                 self._prefix_scatter_j = jax.jit(_prefix_scatter)
-                self._paged_admit_j = jax.jit(
-                    functools.partial(_paged_admit, bb_host))
+                self._paged_admit_j = jax.jit(_paged_admit)
             else:
-                # -- sharded jit family -------------------------------------
-                # Everything below runs under ONE shard_map over the
-                # ("data"=1, "model"=tp) mesh: q/k/v/o projections and the
-                # paged KV pools are head-sharded per the serving plan, all
-                # other operands replicated.  The tp_context arms the
-                # all-reduce hooks in models/layers.py at trace time.
-                rep = P()
-                self._rep_sharding = SH.named(mesh, rep)
-                self._bb_specs = SH.serving_param_specs(
-                    plan, jax.eval_shape(lambda: bb_host))
-                self._bb_sharded = jax.device_put(
-                    bb_host, SH.named(mesh, self._bb_specs))
-                self._bb_arg = (self._bb_sharded,)
                 cache_shape = jax.eval_shape(
                     lambda: T.init_paged_cache(cfg, n_slots, self._n_pages,
                                                ps,
@@ -651,50 +669,15 @@ class EngineCore:
                                                         plan.attn), t),
                     state=lambda t: jax.tree.map(lambda x: P(), t))
                 self._cache_specs = cache_specs
-
-                def shard_body(fn, kw=None):
-                    kw2 = kw or {}
-
-                    def body(*ops):
-                        with CO.tp_context("model", attn=plan.attn,
-                                           mlp=plan.mlp):
-                            return fn(*ops, **kw2)
-                    return body
-
-                def shard_jit(fn, in_specs, out_specs):
-                    return jax.jit(compat.shard_map(
-                        shard_body(fn), mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs))
-
-                def shard_jit_av(fn, in_specs, out_specs):
-                    """Sharded jit keeping ``answer_vocab`` a static kwarg
-                    (the shard_map is staged per static value inside jit's
-                    trace cache, exactly one compile per vocab)."""
-                    @functools.partial(jax.jit,
-                                       static_argnames=("answer_vocab",))
-                    def call(*args, answer_vocab):
-                        return compat.shard_map(
-                            shard_body(fn, {"answer_vocab": answer_vocab}),
-                            mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs)(*args)
-                    return call
-
-                def shard_jit_ml(fn, in_specs, out_specs):
-                    @functools.partial(jax.jit,
-                                       static_argnames=("max_len",))
-                    def call(*args, max_len):
-                        return compat.shard_map(
-                            shard_body(fn, {"max_len": max_len}),
-                            mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs)(*args)
-                    return call
-
-                self._slot_step_j = shard_jit_av(
+                self._slot_step_j = shard_jit(
                     _slot_step_paged,
                     (self._bb_specs, rep, cache_specs, rep, rep, rep),
-                    (rep, rep, cache_specs, rep))
+                    (rep, rep, cache_specs, rep), ("answer_vocab",))
+                self._prefill_prefix_j = shard_jit(
+                    _prefill_prefix, (self._p_specs, rep), dense_specs)
                 self._prefix_scatter_j = shard_jit(
-                    _prefix_scatter, (cache_specs, rep, rep), cache_specs)
+                    _prefix_scatter, (cache_specs, dense_specs, rep),
+                    cache_specs)
                 self._paged_admit_j = shard_jit(
                     _paged_admit,
                     (self._bb_specs, rep, cache_specs, rep, rep, rep, rep,
@@ -705,12 +688,12 @@ class EngineCore:
         if self.cfg.prefill_chunk:
             C = self._chunk
 
-            def _region_embed(images):
+            def _region_embed(p, images):
                 """V(x) only — the learned patch projection, a single small
                 matmul.  This is ALL the model work chunked admission does
                 synchronously; the N_r-token transformer prefill itself
                 streams through later fused steps."""
-                return EO.encode_regions(params, ac, images)
+                return EO.encode_regions(p, ac, images)
 
             def _staging_scatter(staging, embs, slots):
                 """Write K freshly-projected region-embed rows into the
@@ -766,14 +749,13 @@ class EngineCore:
             self._staging_scatter_j = jax.jit(_staging_scatter)
             if mesh is None:
                 self._fused_step_j = jax.jit(
-                    functools.partial(_fused_step, bb_host),
-                    static_argnames=("answer_vocab",))
+                    _fused_step, static_argnames=("answer_vocab",))
             else:
-                self._fused_step_j = shard_jit_av(
+                self._fused_step_j = shard_jit(
                     _fused_step,
                     (self._bb_specs, rep, cache_specs, rep, rep, rep, rep,
                      rep, rep, rep),
-                    (rep, rep, rep, cache_specs))
+                    (rep, rep, rep, cache_specs), ("answer_vocab",))
             #: scene → dict(slot, pages, progress, order): region streams
             #: currently being chunk-prefilled (FIFO by ``order``)
             self._streaming: Dict[Any, Dict[str, Any]] = {}
@@ -783,15 +765,15 @@ class EngineCore:
         # -- speculative-decoding machinery (spec_gamma > 0) ----------------
         if self.cfg.spec_gamma:
             gam = self.cfg.spec_gamma
-            dparams, dcfg = draft.params, draft.cfg
+            dcfg = draft.cfg
+            self._dp = draft.params
             self._draft_max_len = self._slot_max_len + gam
 
-            def _draft_prefill(images, ptok, *, max_len):
+            def _draft_prefill(dp, images, ptok, *, max_len):
                 """Drafter-side [regions | prompt] prefill: the compact
                 model mirrors the slot table on its own small dense cache
                 (no page pool — its KV is cheap and never shared)."""
-                return EO.prefill_tokens(dparams, dcfg, ac, images, ptok,
-                                         max_len)
+                return EO.prefill_tokens(dp, dcfg, ac, images, ptok, max_len)
 
             def _draft_scatter(draft_cache, cache, slots):
                 """Gather+select scatter of K freshly-prefilled drafter rows
@@ -833,9 +815,9 @@ class EngineCore:
                      logits_all[:, :-1, :answer_vocab]], axis=1), axis=-1)
                 return n_commit, new_logits, new_cache, new_index, tok_probs
 
-            def _spec_step(bb, slot_logits, slot_cache, slot_index, active,
-                           block_table, draft_cache, pending, pending_len,
-                           *, answer_vocab):
+            def _spec_step(bb, dp, slot_logits, slot_cache, slot_index,
+                           active, block_table, draft_cache, pending,
+                           pending_len, *, answer_vocab):
                 """Full speculative step: γ+1 compact-model draft feeds
                 (piggybacked ``pending`` drafts override the drafter's
                 argmax where provided and are fed THROUGH it, so its cache
@@ -848,7 +830,7 @@ class EngineCore:
                 def body(carry, j):
                     tok, dcache, i = carry
                     dlogits, dcache = T.decode_step(
-                        dparams["backbone"], dcfg, dcache,
+                        dp["backbone"], dcfg, dcache,
                         {"tokens": tok[:, None]}, i)
                     nxt = jnp.argmax(dlogits[:, :answer_vocab],
                                      axis=-1).astype(jnp.int32)
@@ -883,7 +865,7 @@ class EngineCore:
                                                  active, block_table,
                                                  answer_vocab)
 
-            def _draft_feed(draft_cache, toks, idx):
+            def _draft_feed(dp, draft_cache, toks, idx):
                 """Mirror tokens committed OUTSIDE a spec step (the chunked
                 engine's fused steps advance decode rows through the plain
                 1-token path) into the drafter's cache at per-row ``idx``.
@@ -896,9 +878,8 @@ class EngineCore:
                 rows that are re-prefilled wholesale before their next
                 draft (transition prefill / admission), so nothing ever
                 reads it."""
-                _, dcache = T.decode_step(dparams["backbone"], dcfg,
-                                          draft_cache, {"tokens":
-                                                        toks[:, None]}, idx)
+                _, dcache = T.decode_step(dp["backbone"], dcfg, draft_cache,
+                                          {"tokens": toks[:, None]}, idx)
                 return dcache
 
             if mesh is None:
@@ -907,30 +888,31 @@ class EngineCore:
                 self._draft_scatter_j = jax.jit(_draft_scatter)
                 self._draft_feed_j = jax.jit(_draft_feed)
                 self._spec_step_j = jax.jit(
-                    functools.partial(_spec_step, bb_host),
-                    static_argnames=("answer_vocab",))
+                    _spec_step, static_argnames=("answer_vocab",))
                 self._spec_verify_j = jax.jit(
-                    functools.partial(_spec_verify, bb_host),
-                    static_argnames=("answer_vocab",))
+                    _spec_verify, static_argnames=("answer_vocab",))
             else:
-                # drafter params stay replicated closure constants, but the
-                # draft jits run under the SAME shard_map (all-replicated
-                # specs): the draft cache cycles through the sharded spec
-                # step, so keeping every producer on the mesh stops it
-                # bouncing between committed placements
-                self._draft_prefill_j = shard_jit_ml(_draft_prefill,
-                                                     rep, rep)
+                # drafter params are replicated, and the draft jits run
+                # under the SAME shard_map (all-replicated specs): the draft
+                # cache cycles through the sharded spec step, so keeping
+                # every producer on the mesh stops it bouncing between
+                # committed placements
+                self._dp = self._commit_rep(self._dp)
+                self._draft_prefill_j = shard_jit(_draft_prefill, rep, rep,
+                                                  ("max_len",))
                 self._draft_scatter_j = shard_jit(_draft_scatter, rep, rep)
                 self._draft_feed_j = shard_jit(_draft_feed, rep, rep)
-                self._spec_step_j = shard_jit_av(
+                self._spec_step_j = shard_jit(
                     _spec_step,
-                    (self._bb_specs, rep, cache_specs, rep, rep, rep, rep,
-                     rep, rep),
-                    (rep, rep, rep, cache_specs, rep, rep, rep))
-                self._spec_verify_j = shard_jit_av(
+                    (self._bb_specs, rep, rep, cache_specs, rep, rep, rep,
+                     rep, rep, rep),
+                    (rep, rep, rep, cache_specs, rep, rep, rep),
+                    ("answer_vocab",))
+                self._spec_verify_j = shard_jit(
                     _spec_verify,
                     (self._bb_specs, rep, cache_specs, rep, rep, rep, rep),
-                    (rep, rep, rep, cache_specs, rep, rep))
+                    (rep, rep, rep, cache_specs, rep, rep),
+                    ("answer_vocab",))
 
         # runtime half of spacelint (repro.analysis): warmup() compiles
         # every slot-path executable, then arms the guard — any cache
@@ -1028,7 +1010,8 @@ class EngineCore:
     # ------------------------------------------------------------------
     def encode(self, task: str, images: jax.Array, prompts: jax.Array):
         """V(x), E(T) and pooled visual features: (B,R,d), (B,1,d), (B,d)."""
-        return self._encode_j(images, self.ac.prompt_token(task, prompts))
+        return self._encode_j(self._p, images,
+                              self.ac.prompt_token(task, prompts))
 
     def encode_cached(self, task: str, images: jax.Array, prompts: jax.Array,
                       scene: Optional[Any] = None,
@@ -1062,16 +1045,18 @@ class EngineCore:
     def prefill(self, task: str, images: jax.Array, prompts: jax.Array,
                 extra_len: int):
         max_len = self.ac.n_regions + 1 + extra_len
-        return self._prefill_j(images, self.ac.prompt_token(task, prompts),
+        return self._prefill_j(self._p, images,
+                               self.ac.prompt_token(task, prompts),
                                max_len=max_len)
 
     def decode_chunk(self, cache, logits, idx, n_tokens: int,
                      answer_vocab: int):
-        return self._decode_chunk_j(cache, logits, idx, n_tokens=n_tokens,
+        return self._decode_chunk_j(self._p, cache, logits, idx,
+                                    n_tokens=n_tokens,
                                     answer_vocab=answer_vocab)
 
     def token_features(self, tokens: jax.Array) -> jax.Array:
-        return self._token_feats_j(tokens)
+        return self._token_feats_j(self._p, tokens)
 
     def generate(self, task: str, images: jax.Array, prompts: jax.Array,
                  answer_vocab: int) -> Tuple[jax.Array, jax.Array]:
@@ -1111,9 +1096,9 @@ class EngineCore:
                 T.init_cache(self.draft.cfg, self.cfg.slots,
                              self._draft_max_len))
         if self.cfg.prefill_chunk and self._staging is None:
-            self._staging = jnp.zeros(
+            self._staging = self._commit_rep(jnp.zeros(
                 (self.cfg.slots, self.ac.n_regions, self.tier.cfg.d_model),
-                jnp.dtype(self.tier.cfg.dtype))
+                jnp.dtype(self.tier.cfg.dtype)))
 
     def _commit_rep(self, x):
         """Replicate a host-built value onto the mesh (identity when
@@ -1193,19 +1178,19 @@ class EngineCore:
             # the engine falls back to at steady state
             for k in sorted(sizes):
                 images = jnp.zeros((k,) + shape, jnp.float32)
-                embs = self._region_embed_j(images)
+                embs = self._region_embed_j(self._p, images)
                 drop = jnp.full((k,), self.cfg.slots, jnp.int32)
                 self._staging_scatter_j(self._staging, embs, drop)
                 if self.cfg.spec_gamma:
                     _, dcache, _ = self._draft_prefill_j(
-                        images, jnp.zeros((k,), jnp.int32),
+                        self._dp, images, jnp.zeros((k,), jnp.int32),
                         max_len=self._draft_max_len)
                     self._draft_scatter_j(self._draft_cache, dcache, drop)
             if self.cfg.spec_gamma:
                 zs = jnp.zeros((self.cfg.slots,), jnp.int32)
-                self._draft_feed_j(self._draft_cache, zs, zs)
+                self._draft_feed_j(self._dp, self._draft_cache, zs, zs)
             tb = self._token_budget
-            self._fused_step_j(*self._bb_arg,
+            self._fused_step_j(self._bb,
                                self._slot_logits, self._slot_cache,
                                self._block_table_dev(), self._staging,
                                jnp.full((tb,), self.cfg.slots, jnp.int32),
@@ -1219,21 +1204,21 @@ class EngineCore:
         for k in sorted(sizes):
             images = jnp.zeros((k,) + shape, jnp.float32)
             if self.cache_impl == "paged":
-                cache = self._prefill_prefix_j(images)
+                cache = self._prefill_prefix_j(self._p, images)
                 trash = jnp.zeros((k * self._n_shared_pages,), jnp.int32)
                 self._prefix_scatter_j(self._slot_cache, cache, trash)
                 state = T.map_cache_kinds(
                     self.tier.cfg, [cache],
                     kv=lambda _t: None, state=lambda t: t)
                 self._paged_admit_j(
-                    *self._bb_arg,
+                    self._bb,
                     self._slot_logits, self._slot_cache, self._slot_index,
                     self._block_table_dev(),
                     jnp.full((k,), self.cfg.slots, jnp.int32),
                     jnp.zeros((k,), jnp.int32), state)
                 if self.cfg.spec_gamma:
                     _, dcache, _ = self._draft_prefill_j(
-                        images, jnp.zeros((k,), jnp.int32),
+                        self._dp, images, jnp.zeros((k,), jnp.int32),
                         max_len=self._draft_max_len)
                     self._draft_scatter_j(self._draft_cache, dcache,
                                           jnp.full((k,), self.cfg.slots,
@@ -1241,7 +1226,7 @@ class EngineCore:
             else:
                 ptok = jnp.zeros((k,), jnp.int32)
                 logits, cache, idx = self._prefill_j(
-                    images, ptok, max_len=self._slot_max_len)
+                    self._p, images, ptok, max_len=self._slot_max_len)
                 drop = jnp.full((k,), self.cfg.slots, jnp.int32)
                 self._slot_scatter_many_j(self._slot_cache, self._slot_logits,
                                           self._slot_index, cache, logits,
@@ -1262,19 +1247,19 @@ class EngineCore:
             # block-table rows point at the trash page, outputs discarded)
             pend = jnp.zeros((self.cfg.slots, self.cfg.spec_gamma),
                              jnp.int32)
-            self._spec_step_j(*self._bb_arg,
+            self._spec_step_j(self._bb, self._dp,
                               self._slot_logits, self._slot_cache,
                               self._slot_index, inactive,
                               self._block_table_dev(), self._draft_cache,
                               pend, jnp.zeros((self.cfg.slots,), jnp.int32),
                               answer_vocab=self.cfg.answer_vocab)
-            self._spec_verify_j(*self._bb_arg,
+            self._spec_verify_j(self._bb,
                                 self._slot_logits, self._slot_cache,
                                 self._slot_index, inactive,
                                 self._block_table_dev(), pend,
                                 answer_vocab=self.cfg.answer_vocab)
         else:
-            self._slot_step_j(*self._bb_arg,
+            self._slot_step_j(self._bb,
                               self._slot_logits, self._slot_cache,
                               self._slot_index, inactive,
                               *self._step_args(),
@@ -1337,7 +1322,8 @@ class EngineCore:
         ptok[k:] = ptok[k - 1]
         # fixed max_len: every request uses the same cache capacity, so the
         # prefill and decode step never see a new sequence length
-        logits, cache, idx = self._prefill_j(images, jnp.asarray(ptok),
+        logits, cache, idx = self._prefill_j(self._p, images,
+                                             jnp.asarray(ptok),
                                              max_len=self._slot_max_len)
         self._slot_cache, self._slot_logits, self._slot_index = \
             self._slot_scatter_many_j(self._slot_cache, self._slot_logits,
@@ -1400,7 +1386,7 @@ class EngineCore:
         images = jnp.asarray(np.stack(
             [np.asarray(r.image) for _, r in miss]
             + [np.asarray(miss[-1][1].image)] * (kpad - km)))
-        cache = self._prefill_prefix_j(images)
+        cache = self._prefill_prefix_j(self._p, images)
         pages = np.full((kpad, n_shared), TRASH_PAGE, np.int32)
         allocs = []
         for i in range(km):
@@ -1467,7 +1453,7 @@ class EngineCore:
             lambda *xs: jnp.concatenate(xs, axis=1), *states_pad)
 
         self._slot_logits, self._slot_cache, self._slot_index = \
-            self._paged_admit_j(*self._bb_arg,
+            self._paged_admit_j(self._bb,
                                 self._slot_logits, self._slot_cache,
                                 self._slot_index, self._block_table_dev(),
                                 jnp.asarray(admit_slots),
@@ -1482,7 +1468,7 @@ class EngineCore:
                 [np.asarray(r.image) for r in requests]
                 + [np.asarray(requests[-1].image)] * (kpad - k)))
             _, dcache, _ = self._draft_prefill_j(
-                imgs, jnp.asarray(ptoks_pad, jnp.int32),
+                self._dp, imgs, jnp.asarray(ptoks_pad, jnp.int32),
                 max_len=self._draft_max_len)
             self._draft_cache = self._draft_scatter_j(
                 self._draft_cache, dcache, jnp.asarray(admit_slots))
@@ -1571,7 +1557,7 @@ class EngineCore:
             kpad = self._admit_pad(km, self.cfg.slots)
             imgs = jnp.asarray(np.stack(
                 stream_imgs + [stream_imgs[-1]] * (kpad - km)))
-            embs = self._region_embed_j(imgs)
+            embs = self._region_embed_j(self._p, imgs)
             slots_pad = np.asarray(stream_slots
                                    + [self.cfg.slots] * (kpad - km), np.int32)
             self._staging = self._staging_scatter_j(self._staging, embs,
@@ -1873,7 +1859,7 @@ class EngineCore:
         if self._active_dev is None:
             self._active_dev = jnp.asarray([s.active for s in self._slots])
         toks, self._slot_logits, self._slot_cache, self._slot_index = \
-            self._slot_step_j(*self._bb_arg,
+            self._slot_step_j(self._bb,
                               self._slot_logits, self._slot_cache,
                               self._slot_index, self._active_dev,
                               *self._step_args(),
@@ -1988,7 +1974,7 @@ class EngineCore:
 
         tok, probs0, self._slot_logits, self._slot_cache = \
             self._fused_step_j(
-                *self._bb_arg,
+                self._bb,
                 self._slot_logits, self._slot_cache,
                 self._block_table_dev(), self._staging,
                 jnp.asarray(srow), jnp.asarray(tokens), jnp.asarray(pos),
@@ -2033,7 +2019,8 @@ class EngineCore:
                 dtoks[i] = toks_np[jf]
                 didx[i] = pos[jf]
             self._draft_cache = self._draft_feed_j(
-                self._draft_cache, jnp.asarray(dtoks), jnp.asarray(didx))
+                self._dp, self._draft_cache, jnp.asarray(dtoks),
+                jnp.asarray(didx))
 
         finished: List[Tuple[Request, np.ndarray]] = []
         for i in decode_rows:
@@ -2099,7 +2086,8 @@ class EngineCore:
             ptoks[j] = self.ac.prompt_id(slot.request.task,
                                          slot.request.prompt)
         ptoks[km:] = ptoks[km - 1]
-        _, dcache, _ = self._draft_prefill_j(imgs, jnp.asarray(ptoks),
+        _, dcache, _ = self._draft_prefill_j(self._dp, imgs,
+                                             jnp.asarray(ptoks),
                                              max_len=self._draft_max_len)
         slots_pad = np.asarray(rows + [self.cfg.slots] * (kpad - km),
                                np.int32)
@@ -2150,14 +2138,14 @@ class EngineCore:
         if verify_only:
             chunk, n_commit, self._slot_logits, self._slot_cache, \
                 self._slot_index, tok_probs = self._spec_verify_j(
-                    *self._bb_arg, *args, jnp.asarray(pend),
+                    self._bb, *args, jnp.asarray(pend),
                     answer_vocab=self.cfg.answer_vocab)
             sp["verify_only_steps"] += 1
         else:
             chunk, n_commit, self._slot_logits, self._slot_cache, \
                 self._slot_index, tok_probs, self._draft_cache = \
                 self._spec_step_j(
-                    *self._bb_arg, *args, self._draft_cache,
+                    self._bb, self._dp, *args, self._draft_cache,
                     jnp.asarray(pend),
                     jnp.asarray(plen), answer_vocab=self.cfg.answer_vocab)
         # spacelint: disable=SL001 (the single deliberate per-step fetch: the verified chunk must reach the host-side scheduler)

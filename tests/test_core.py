@@ -98,6 +98,28 @@ def test_multiscale_piecewise_rules():
     assert float(meta["compression_ratio"][0]) > 1.0
 
 
+def test_multiscale_partial_cells_at_level_8():
+    """A 28-px Qwen2-VL region at level 8 pools into ⌈28/8⌉² = 16 cells per
+    channel: the edge cells average only the pixels they hold, and all 16
+    are counted as sent."""
+    hw, ch = 28, 3
+    regions = jax.random.uniform(KEY, (1, 2, hw, hw, ch))
+    # c = 0.2 / (0.36 - 0.35) = 20 → level 8; second region dropped
+    scores = jnp.asarray([[0.36, 0.1]])
+    out, tx, meta = PP.multiscale_filter(regions, scores, alpha=0.35,
+                                         beta=0.55)
+    assert float(meta["levels"][0, 0]) == 8.0
+    x, o = np.asarray(regions)[0, 0], np.asarray(out)[0, 0]
+    for i0 in range(0, hw, 8):
+        for j0 in range(0, hw, 8):
+            cell = x[i0:i0 + 8, j0:j0 + 8]      # 4 px wide at the edge
+            np.testing.assert_allclose(
+                o[i0:i0 + 8, j0:j0 + 8],
+                np.broadcast_to(cell.mean(axis=(0, 1)), cell.shape),
+                rtol=1e-5, atol=1e-6)
+    assert float(tx[0]) == 16 * ch * 3.0
+
+
 def test_multiscale_scale_factor_formula():
     scores = jnp.asarray([0.35, 0.45, 0.549, 0.55, 0.9])
     c = np.asarray(PP.scale_factor(scores, 0.35, 0.55))

@@ -16,7 +16,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.configs.shapes import ShapeSpec
-from repro.distributed import compat
 from repro.distributed import hlo_parser
 from repro.distributed import sharding as SH
 from repro.launch import specs as SP
@@ -69,10 +68,10 @@ def test_small_mesh_train_lowering_compiles():
     cfg = configs.get_config("glm4-9b", reduced=True)
     shape = ShapeSpec("t", 32, 8, "train")
     from repro.launch.dryrun import build_lowerable
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, arg_specs = build_lowerable(cfg, shape, mesh)
         compiled = fn.lower(*arg_specs).compile()
-    assert compat.cost_analysis(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
 
 
 def test_small_mesh_decode_lowering_compiles():
@@ -80,7 +79,7 @@ def test_small_mesh_decode_lowering_compiles():
     cfg = configs.get_config("gemma2-27b", reduced=True)
     shape = ShapeSpec("d", 64, 8, "decode")
     from repro.launch.dryrun import build_lowerable
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, arg_specs = build_lowerable(cfg, shape, mesh)
         compiled = fn.lower(*arg_specs).compile()
     analysis = hlo_parser.analyze(compiled.as_text())
@@ -105,10 +104,9 @@ def test_hlo_parser_trip_counts_and_flops():
 def test_hlo_parser_collectives_detected():
     mesh = jax.make_mesh((8,), ("m",))
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda x, w: (x @ w).sum(),
-                    in_shardings=compat.shardings(
-                        mesh, (P(None, "m"), P("m", None))))
+                    in_shardings=(P(None, "m"), P("m", None)))
         c = f.lower(a, a).compile()
     s = hlo_parser.analyze(c.as_text())
     assert s["collectives"]["total"]["link_bytes"] > 0

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import decode_attention_pallas
 
 KEY = jax.random.PRNGKey(7)
 
@@ -60,6 +61,7 @@ def test_region_score_matches_manual_cosine():
 
 @pytest.mark.parametrize("sq,h,kh,hd", [
     (128, 4, 4, 32), (256, 8, 2, 32), (128, 4, 1, 64), (256, 2, 2, 16),
+    (200, 4, 2, 32),    # ragged tail block: N_r + 1 style lengths
 ])
 @pytest.mark.parametrize("window,softcap", [(0, None), (64, None),
                                             (0, 50.0), (96, 30.0)])
@@ -123,6 +125,29 @@ def test_decode_attention_sweep(s, h, kh, hd, clen, window):
     got = ops.decode_attention(q, k, v, jnp.int32(clen), window=window,
                                impl="pallas_interpret")
     want = ref.decode_attention(q, k, v, jnp.int32(clen), window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.kernel_parity
+@pytest.mark.parametrize("s,clen,q_len", [
+    (300, 300, 1), (300, 290, 1), (300, 137, 1), (300, 300, 3),
+    (2052, 2052, 4),
+])
+def test_decode_attention_ragged_tail_block(s, clen, q_len):
+    """A KV axis that is not a multiple of the tile runs on a cdiv grid;
+    the tail block's rows past S must not leak into the result."""
+    h, kh, hd = 6, 2, 128
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    q = _rand(k1, (2, q_len, h, hd), jnp.float32)
+    k = _rand(k2, (2, s, kh, hd), jnp.float32)
+    v = _rand(k3, (2, s, kh, hd), jnp.float32)
+    lens = jnp.asarray([clen, max(clen - 5, q_len)], jnp.int32)
+    got = ops._rows_to_chunk(decode_attention_pallas(
+        ops._chunk_to_rows(q, kh), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), lens, q_len=q_len, kv_blk=128,
+        interpret=True), q_len, h)
+    want = ref.multi_decode_attention(q, k, v, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 

@@ -49,6 +49,21 @@ def test_arch_smoke_forward_and_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", configs.ASSIGNED)
+def test_init_params_matches_per_block_loop(arch):
+    """``init_params`` vmaps each pattern position's block init over the
+    super-blocks; the weights are bitwise those of one init per block."""
+    cfg = configs.get_config(arch, reduced=True)
+    params = T.init_params(cfg, KEY)
+    _, kb = jax.random.split(KEY)
+    for pos, spec in enumerate(cfg.block_pattern):
+        keys = jax.random.split(jax.random.fold_in(kb, pos), cfg.n_super)
+        loop = jax.tree.map(lambda *xs: jnp.stack(xs),
+                            *[T._init_block(k, cfg, spec) for k in keys])
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), params["blocks"][pos], loop)
+
+
+@pytest.mark.parametrize("arch", configs.ASSIGNED)
 def test_arch_smoke_prefill_decode(arch):
     cfg = configs.get_config(arch, reduced=True)
     params = T.init_params(cfg, KEY)
