@@ -1,0 +1,8 @@
+"""Model FLOPs of every prefill and decode token done in the window (scene
+prefixes, prompt rows, decode rows) over the window times the chips' bf16
+peak.  Moves ``ttft_p95_ms``."""
+from harness import readers
+
+
+def read(run):
+    return readers.mfu(run)
