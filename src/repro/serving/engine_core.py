@@ -62,6 +62,7 @@ from repro.distributed import collectives as CO
 from repro.distributed import sharding as SH
 from repro.kernels import kv_quant
 from repro.models import transformer as T
+from repro.serving import obs
 from repro.serving.admission import (ADMITTED, QUEUED, REJECTED,
                                      REASON_EXPIRED, REASON_INFEASIBLE,
                                      REASON_QUEUE_FULL,
@@ -954,8 +955,6 @@ class EngineCore:
             #: prompt suffixes), "chunk" (region tokens streamed by the
             #: chunked engine), "draft" (drafter-side prefills, spec only)
             "prefill_by_kind": {},
-            "encode_reuse": 0,          # serve-path scene-encode cache hits
-            "occupancy_log": [],        # (step, active_slots_after_admit)
             #: finished-request milestones (bounded):
             #: {request_id, task, t_admit, t_first, t_done} wall-clock —
             #: the serving bench derives TTFT / latency percentiles from it
@@ -1003,7 +1002,7 @@ class EngineCore:
                 "emitted": 0,           # committed tokens kept (≤ l_ans)
                 "piggybacked": 0,       # drafts supplied by the satellite
             }
-        self._occupancy_cap = 4096      # keep the log bounded on long runs
+        self._log_cap = 4096            # keep the logs bounded on long runs
 
     # ------------------------------------------------------------------
     # batch path (shared by CascadeExecutor)
@@ -1034,7 +1033,6 @@ class EngineCore:
         hit = self._encode_cache.get(key)
         if hit is not None:
             self._encode_cache.move_to_end(key)
-            self.stats["encode_reuse"] += 1
             return hit
         out = self.encode(task, images, prompts)
         self._encode_cache[key] = out
@@ -1291,9 +1289,22 @@ class EngineCore:
         region prefix prefills once per **unique scene not already
         page-resident**, then every request maps the shared prefix pages
         read-only and runs only its 1-token prompt suffix (see
-        ``_admit_many_paged``).  Returns the slot id per request."""
+        ``_admit_many_paged``).  Returns the slot id per request.
+
+        Every flavour runs inside one ``engine.admit`` span (``obs``)."""
         if not requests:
             return []
+        k = len(requests)
+        with obs.span("engine.admit", requests=k,
+                      bucket=self._admit_pad(k, self.cfg.slots),
+                      request_ids=[r.request_id for r in requests]) as sp:
+            m0 = self.stats["prefix_misses"]
+            out = self._admit_many(requests)
+            sp.attrs["misses"] = self.stats["prefix_misses"] - m0
+            sp.attrs["active_after"] = self.active_count()
+        return out
+
+    def _admit_many(self, requests: List[Request]) -> List[int]:
         t_admit = time.perf_counter()      # arrival at the engine: TTFT
         free = self.free_slots()           # clocks start BEFORE any prefill
         if len(requests) > len(free):
@@ -1338,39 +1349,37 @@ class EngineCore:
                            requests: List[Request], scenes=None,
                            private=None, phases=None,
                            t_admit: Optional[float] = None) -> None:
-        log = self.stats["occupancy_log"]
-        # t_admit is captured at admit_many ENTRY: stamping here would run
-        # AFTER the synchronous scene prefill and hide the very admission
-        # stall the TTFT instrumentation exists to expose
-        now = t_admit if t_admit is not None else time.perf_counter()
-        for j, (s, request) in enumerate(zip(slot_ids, requests)):
-            others_active = self.active_count()
-            pending = None
-            if self.cfg.spec_gamma and request.draft_tokens is not None:
-                # Request.__post_init__ normalised drafts to flat host
-                # int32 — no device fetch happens here
-                pending = [int(t) for t in request.draft_tokens]
-            # per-token probs are only materialised for requests that will
-            # read them (generate_spec) — plain slot-path serving never
-            # pays the host transfer / per-token appends
-            wants_probs = (self.cfg.spec_gamma
-                           and getattr(request, "_wants_probs", False))
-            self._slots[s] = _Slot(
-                request=request, l_ans=self.ac.answer_len(request.task),
-                tokens=[], active=True,
-                scene=scenes[j] if scenes else None,
-                private_pages=private[j] if private else None,
-                pending_drafts=pending,
-                probs=[] if wants_probs else None,
-                phase=phases[j] if phases else "decode",
-                t_admit=now)
-            self.stats["admitted"] += 1
-            if self._step_no > 0 and others_active > 0:
-                self.stats["mid_stream_refills"] += 1
-            log.append((self._step_no, self.active_count()))
-        self._active_dev = None
-        if len(log) > self._occupancy_cap:
-            del log[:self._occupancy_cap // 2]
+        with obs.span("engine.admit.record"):
+            # the slots taken here are free until this loop marks them active
+            active = self.active_count()
+            # t_admit is captured at admit_many ENTRY: stamping here would run
+            # AFTER the synchronous scene prefill and hide the very admission
+            # stall the TTFT instrumentation exists to expose
+            now = t_admit if t_admit is not None else time.perf_counter()
+            for j, (s, request) in enumerate(zip(slot_ids, requests)):
+                pending = None
+                if self.cfg.spec_gamma and request.draft_tokens is not None:
+                    # Request.__post_init__ normalised drafts to flat host
+                    # int32 — no device fetch happens here
+                    pending = [int(t) for t in request.draft_tokens]
+                # per-token probs are only materialised for requests that will
+                # read them (generate_spec) — plain slot-path serving never
+                # pays the host transfer / per-token appends
+                wants_probs = (self.cfg.spec_gamma
+                               and getattr(request, "_wants_probs", False))
+                self._slots[s] = _Slot(
+                    request=request, l_ans=self.ac.answer_len(request.task),
+                    tokens=[], active=True,
+                    scene=scenes[j] if scenes else None,
+                    private_pages=private[j] if private else None,
+                    pending_drafts=pending,
+                    probs=[] if wants_probs else None,
+                    phase=phases[j] if phases else "decode",
+                    t_admit=now)
+                self.stats["admitted"] += 1
+                if self._step_no > 0 and active + j > 0:
+                    self.stats["mid_stream_refills"] += 1
+            self._active_dev = None
 
     # -- paged admission ------------------------------------------------
     def _prefill_prefixes(self, miss: List[Tuple[Any, Request]]) -> None:
@@ -1383,23 +1392,25 @@ class EngineCore:
         km = len(miss)
         n_shared = self._n_shared_pages
         kpad = self._admit_pad(km, self.cfg.slots)
-        images = jnp.asarray(np.stack(
-            [np.asarray(r.image) for _, r in miss]
-            + [np.asarray(miss[-1][1].image)] * (kpad - km)))
-        cache = self._prefill_prefix_j(self._p, images)
-        pages = np.full((kpad, n_shared), TRASH_PAGE, np.int32)
-        allocs = []
-        for i in range(km):
-            pg = self._pool.alloc(n_shared)
-            allocs.append(pg)
-            pages[i] = pg
-        self._slot_cache = self._prefix_scatter_j(
-            self._slot_cache, cache, jnp.asarray(pages.reshape(-1)))
-        state_tree = T.map_cache_kinds(self.tier.cfg, [cache],
-                                       kv=lambda _t: None, state=lambda t: t)
-        for i, (scene, _r) in enumerate(miss):
-            row = jax.tree.map(lambda x: x[:, i:i + 1], state_tree)
-            self._prefix.put(scene, allocs[i], row)
+        with obs.span("engine.admit.prefill", scenes=km) as sp:
+            stack = np.stack([np.asarray(r.image) for _, r in miss]
+                             + [np.asarray(miss[-1][1].image)] * (kpad - km))
+            sp.attrs["image_bytes"] = stack.nbytes
+            cache = self._prefill_prefix_j(self._p, jnp.asarray(stack))
+            pages = np.full((kpad, n_shared), TRASH_PAGE, np.int32)
+            allocs = []
+            for i in range(km):
+                pg = self._pool.alloc(n_shared)
+                allocs.append(pg)
+                pages[i] = pg
+            self._slot_cache = self._prefix_scatter_j(
+                self._slot_cache, cache, jnp.asarray(pages.reshape(-1)))
+            state_tree = T.map_cache_kinds(self.tier.cfg, [cache],
+                                           kv=lambda _t: None,
+                                           state=lambda t: t)
+            for i, (scene, _r) in enumerate(miss):
+                row = jax.tree.map(lambda x: x[:, i:i + 1], state_tree)
+                self._prefix.put(scene, allocs[i], row)
         self.stats["prefix_misses"] += km
         self._note_prefill("prefix", km * self.ac.n_regions)
 
@@ -1410,55 +1421,59 @@ class EngineCore:
         prompt suffix runs through the model — K queries over one scene
         prefill the ``N_r`` region tokens once."""
         k = len(requests)
-        scenes = [scene_key(r) for r in requests]
-        batch_scenes = set(scenes)
-        miss, seen = [], set()
-        for s_, r in zip(scenes, requests):
-            if s_ not in self._prefix and s_ not in seen:
-                miss.append((s_, r))
-                seen.add(s_)
-        # check-then-commit (admission atomicity): ONE eviction call budgets
-        # the whole batch — shared pages + cache entries for the missing
-        # scenes AND every request's private pages — before anything is
-        # allocated, scattered or made resident.  A MemoryError here leaves
-        # the engine byte-identical to before the call; past this line no
-        # allocation can fail, so a batch can never leak refcounts or leave
-        # partially mapped prefix pages behind.
-        self._prefix.evict_for(
-            k * self._private_per_slot
-            + len(miss) * self._n_shared_pages,
-            need_entries=len(miss), protect=batch_scenes)
+        with obs.span("engine.admit.lookup"):
+            scenes = [scene_key(r) for r in requests]
+            batch_scenes = set(scenes)
+            miss, seen = [], set()
+            for s_, r in zip(scenes, requests):
+                if s_ not in self._prefix and s_ not in seen:
+                    miss.append((s_, r))
+                    seen.add(s_)
+            # check-then-commit (admission atomicity): ONE eviction call
+            # budgets the whole batch — shared pages + cache entries for the
+            # missing scenes AND every request's private pages — before
+            # anything is allocated, scattered or made resident.  A
+            # MemoryError here leaves the engine byte-identical to before
+            # the call; past this line no allocation can fail, so a batch
+            # can never leak refcounts or leave partially mapped prefix
+            # pages behind.
+            self._prefix.evict_for(
+                k * self._private_per_slot
+                + len(miss) * self._n_shared_pages,
+                need_entries=len(miss), protect=batch_scenes)
         if miss:
             self._prefill_prefixes(miss)
         self.stats["prefix_hits"] += k - len(miss)
         target = free[:k]
-        ptoks = np.empty((k,), np.int32)
-        states, private = [], []
-        for i, (r, s_) in enumerate(zip(requests, scenes)):
-            entry = self._prefix.acquire(s_)
-            priv = self._pool.alloc(self._private_per_slot)
-            self._bt_np[target[i]] = list(entry.pages) + priv
-            ptoks[i] = self.ac.prompt_id(r.task, r.prompt)
-            states.append(entry.state)
-            private.append(priv)
-        self._bt_dev = None
+        with obs.span("engine.admit.pack"):
+            ptoks = np.empty((k,), np.int32)
+            states, private = [], []
+            for i, (r, s_) in enumerate(zip(requests, scenes)):
+                entry = self._prefix.acquire(s_)
+                priv = self._pool.alloc(self._private_per_slot)
+                self._bt_np[target[i]] = list(entry.pages) + priv
+                ptoks[i] = self.ac.prompt_id(r.task, r.prompt)
+                states.append(entry.state)
+                private.append(priv)
+            self._bt_dev = None
 
-        kpad = self._admit_pad(k, self.cfg.slots)
-        admit_slots = np.asarray(target + [self.cfg.slots] * (kpad - k),
-                                 np.int32)
-        ptoks_pad = np.concatenate([ptoks,
-                                    np.repeat(ptoks[-1:], kpad - k)])
-        states_pad = states + [states[-1]] * (kpad - k)
-        prefix_state = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=1), *states_pad)
+            kpad = self._admit_pad(k, self.cfg.slots)
+            admit_slots = np.asarray(target + [self.cfg.slots] * (kpad - k),
+                                     np.int32)
+            ptoks_pad = np.concatenate([ptoks,
+                                        np.repeat(ptoks[-1:], kpad - k)])
+            states_pad = states + [states[-1]] * (kpad - k)
+            prefix_state = jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=1), *states_pad)
 
-        self._slot_logits, self._slot_cache, self._slot_index = \
-            self._paged_admit_j(self._bb,
-                                self._slot_logits, self._slot_cache,
-                                self._slot_index, self._block_table_dev(),
-                                jnp.asarray(admit_slots),
-                                jnp.asarray(ptoks_pad, jnp.int32),
-                                prefix_state)
+        with obs.span("engine.admit.dispatch"):
+            self._slot_logits, self._slot_cache, self._slot_index = \
+                self._paged_admit_j(self._bb,
+                                    self._slot_logits, self._slot_cache,
+                                    self._slot_index, self._block_table_dev(),
+                                    jnp.asarray(admit_slots),
+                                    jnp.asarray(ptoks_pad, jnp.int32),
+                                    prefix_state)
         self._note_prefill("prompt", k)        # one prompt token per request
         if self.cfg.spec_gamma:
             # the drafter mirrors the slot table on its own dense cache: one
@@ -1600,8 +1615,8 @@ class EngineCore:
                                  else slot.t_admit),
                     "preempts": (meta["preempts"] if meta is not None
                                  else 0)})
-        if len(log) > self._occupancy_cap:
-            del log[:self._occupancy_cap // 2]
+        if len(log) > self._log_cap:
+            del log[:self._log_cap // 2]
         if slot.probs:
             self._stash_spec_probs(slot)
         self._release_slot(i)
@@ -1725,8 +1740,8 @@ class EngineCore:
         ol["rejections"][reason] = ol["rejections"].get(reason, 0) + 1
         self._submit_meta.pop(entry.request.request_id, None)
         self._rejected.append((entry.request, reason))
-        if len(self._rejected) > self._occupancy_cap:
-            del self._rejected[:self._occupancy_cap // 2]
+        if len(self._rejected) > self._log_cap:
+            del self._rejected[:self._log_cap // 2]
 
     def _pump_queue(self, now: Optional[float] = None) -> None:
         """Admit the longest strictly-priority-ordered queue prefix that
@@ -1783,8 +1798,8 @@ class EngineCore:
                 wait = ol["readmit_wait_s"]
                 wait.append(now - meta["t_preempt"])
                 meta["t_preempt"] = None
-                if len(wait) > self._occupancy_cap:
-                    del wait[:self._occupancy_cap // 2]
+                if len(wait) > self._log_cap:
+                    del wait[:self._log_cap // 2]
 
     def _preempt_one(self, above_priority: int, now: float) -> bool:
         """Preempt ONE in-flight slot whose priority is strictly below
@@ -1846,41 +1861,64 @@ class EngineCore:
         immediately — callers refill them from their pending queue before
         the next ``step`` (continuous batching).  Overload-controlled
         engines additionally pump their own admission queue first, so
-        slots freed by the previous step refill before advancing."""
-        if self._admq is not None:
-            self._pump_queue()
-        if self.cfg.prefill_chunk and any(
-                s.active and s.phase != "decode" for s in self._slots):
-            return self._step_chunked()
-        if self.cfg.spec_gamma:
-            return self._step_spec()
-        if self.active_count() == 0:
-            return []
+        slots freed by the previous step refill before advancing.
+
+        Every flavour runs inside one ``engine.step`` span (``obs``)."""
+        with obs.span("engine.step", slots=self.cfg.slots) as sp:
+            if self._admq is not None:
+                self._pump_queue()
+            sp.attrs["rows"] = rows = self.active_count()
+            if self.cfg.prefill_chunk and any(
+                    s.active and s.phase != "decode" for s in self._slots):
+                return self._step_chunked()
+            if self.cfg.spec_gamma:
+                return self._step_spec()
+            if rows == 0:
+                return []
+            return self._step_plain()
+
+    def _step_plain(self) -> List[Tuple[Request, np.ndarray]]:
+        """One token for every active slot: re-upload what admission or
+        release invalidated, dispatch the step, fetch its tokens, commit."""
+        stale = ()
         if self._active_dev is None:
-            self._active_dev = jnp.asarray([s.active for s in self._slots])
-        toks, self._slot_logits, self._slot_cache, self._slot_index = \
-            self._slot_step_j(self._bb,
-                              self._slot_logits, self._slot_cache,
-                              self._slot_index, self._active_dev,
-                              *self._step_args(),
-                              answer_vocab=self.cfg.answer_vocab)
-        # spacelint: disable=SL001 (the single deliberate per-step fetch: committed tokens must reach the host-side scheduler)
-        toks_np = np.asarray(toks)
-        self._step_no += 1
-        now = time.perf_counter()
-        sched = self.stats["sched"]
-        sched["steps"] += 1
-        finished: List[Tuple[Request, np.ndarray]] = []
-        for i, slot in enumerate(self._slots):
-            if not slot.active:
-                continue
-            slot.tokens.append(int(toks_np[i]))
-            sched["decode_tokens"] += 1
-            if slot.t_first is None:
-                slot.t_first = now
-            if len(slot.tokens) >= slot.l_ans:
-                self._finish_slot(i, finished)
-        self._compile_guard.check("step")
+            stale += ("active",)
+        if self.cache_impl == "paged" and self._bt_dev is None:
+            stale += ("block_table",)
+        if stale:
+            with obs.span("engine.step.upload", what=stale):
+                if "active" in stale:
+                    self._active_dev = jnp.asarray(
+                        [s.active for s in self._slots])
+                if "block_table" in stale:
+                    self._block_table_dev()
+        with obs.span("engine.step.dispatch"):
+            toks, self._slot_logits, self._slot_cache, self._slot_index = \
+                self._slot_step_j(self._bb,
+                                  self._slot_logits, self._slot_cache,
+                                  self._slot_index, self._active_dev,
+                                  *self._step_args(),
+                                  answer_vocab=self.cfg.answer_vocab)
+        with obs.span("engine.step.fetch"):
+            # spacelint: disable=SL001 (the single deliberate per-step fetch: committed tokens must reach the host-side scheduler)
+            toks_np = np.asarray(toks)
+        with obs.span("engine.step.commit") as sp:
+            self._step_no += 1
+            now = time.perf_counter()
+            sched = self.stats["sched"]
+            sched["steps"] += 1
+            finished: List[Tuple[Request, np.ndarray]] = []
+            for i, slot in enumerate(self._slots):
+                if not slot.active:
+                    continue
+                slot.tokens.append(int(toks_np[i]))
+                sched["decode_tokens"] += 1
+                if slot.t_first is None:
+                    slot.t_first = now
+                if len(slot.tokens) >= slot.l_ans:
+                    self._finish_slot(i, finished)
+            self._compile_guard.check("step")
+            sp.attrs["finished"] = [r.request_id for r, _ in finished]
         return finished
 
     def _slot_pos(self, i: int) -> int:
@@ -2002,8 +2040,8 @@ class EngineCore:
             sched["stall_steps"] += 1
         slog = sched["step_log"]
         slog.append((len(decode_rows), n_prompt, n_chunk))
-        if len(slog) > self._occupancy_cap:
-            del slog[:self._occupancy_cap // 2]
+        if len(slog) > self._log_cap:
+            del slog[:self._log_cap // 2]
         self._note_prefill("prompt", n_prompt)
         self._note_prefill("chunk", n_chunk)
 

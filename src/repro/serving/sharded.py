@@ -37,6 +37,7 @@ from jax.sharding import Mesh
 
 from repro.core import eo_adapter as EO
 from repro.distributed import sharding as SH
+from repro.serving import obs
 from repro.serving.engine_core import EngineCore, EngineCoreConfig
 from repro.serving.request import Request, scene_key
 
@@ -189,9 +190,22 @@ class ShardedEngineCore:
     def admit_many(self, requests: List[Request]) -> List[int]:
         """Route + admit a batch; returns GLOBAL slot ids, in request
         order.  One ``admit_many`` per shard that received work — the
-        per-shard calls keep their compiled bucket shapes."""
+        per-shard calls keep their compiled bucket shapes.  Runs inside
+        one ``engine.admit`` span, around each shard's own."""
         if not requests:
             return []
+        with obs.span("engine.admit", requests=len(requests),
+                      request_ids=[r.request_id for r in requests]) as sp:
+            m0 = self._misses()
+            out = self._admit_many(requests)
+            sp.attrs["misses"] = self._misses() - m0
+            sp.attrs["active_after"] = self.active_count()
+        return out
+
+    def _misses(self) -> int:
+        return sum(sh.stats["prefix_misses"] for sh in self._shards)
+
+    def _admit_many(self, requests: List[Request]) -> List[int]:
         free = [len(sh.free_slots()) for sh in self._shards]
         if len(requests) > sum(free):
             raise RuntimeError(
@@ -260,11 +274,14 @@ class ShardedEngineCore:
     def step(self) -> List[Tuple[Request, np.ndarray]]:
         """Advance every shard's slot table; shards step independently
         (their compiled step families share nothing), finished requests
-        concatenate in shard order."""
+        concatenate in shard order.  Runs inside one ``engine.step``
+        span, around each shard's own."""
         self._stepped = True
         finished: List[Tuple[Request, np.ndarray]] = []
-        for sh in self._shards:
-            finished.extend(sh.step())
+        with obs.span("engine.step", slots=self.cfg.slots,
+                      rows=self.active_count()):
+            for sh in self._shards:
+                finished.extend(sh.step())
         return finished
 
     # -- batch-level API: replicated params, any shard answers ------------

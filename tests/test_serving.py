@@ -1,12 +1,14 @@
 """Serving runtime: engine batching + cascade server behaviour."""
 import json
 import os
+import time
 
 import numpy as np
 import pytest
 
 from repro.network.orbit import ContactPlan
-from repro.serving import CascadeServer, EngineConfig, InferenceEngine, Request
+from repro.serving import (CascadeServer, EngineConfig, InferenceEngine,
+                           Request, obs)
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "golden_cascade_server.json")
 
@@ -67,6 +69,7 @@ def test_continuous_batching_refills_slots_mid_stream(tiny_bundle):
     # request pins one slot while 1-token requests stream through the other
     reqs = [Request(task="det", image=data["images"][0], prompt=0)]
     reqs += _requests(tiny_bundle, "vqa", 5)
+    t0 = time.perf_counter()
     resps = eng.serve(reqs)
     assert len(resps) == 6
     assert {r.request_id for r in resps} == {q.request_id for q in reqs}
@@ -75,9 +78,10 @@ def test_continuous_batching_refills_slots_mid_stream(tiny_bundle):
     # ≥4 admissions happened after step 0 with the det slot still active
     assert eng.core.stats["mid_stream_refills"] >= 4
     # the slot table stayed full whenever work was pending: every admission
-    # after the first two saw both slots occupied afterwards
-    occ = eng.core.stats["occupancy_log"]
-    assert all(n == 2 for _, n in occ[2:])
+    # left both slots occupied
+    admits = obs.TRACER.spans("engine.admit", since=t0)
+    assert sum(s.attrs["requests"] for s in admits) == len(reqs)
+    assert all(s.attrs["active_after"] == 2 for s in admits)
 
 
 def test_engine_emits_unified_tier_vocabulary(tiny_bundle):
