@@ -1,13 +1,15 @@
 """Per-(backend, kernel, dtype) tile autotuning for the serving kernels.
 
-The paged Pallas kernels expose tunable tile knobs — page-block fan-in
-``fan`` (how many physical pages one grid step fetches and reduces),
-``q_blk`` (prefill query sub-block rows per program) and the dense decode
-kernel's ``kv_blk`` (KV rows per program) — whose best values depend on the
-executing backend: the interpret oracle pays per-grid-step Python overhead
-(large ``fan`` wins), a real MXU wants tiles near its native shape, and the
-CPU ``ref`` path ignores them entirely.  Hand-picked defaults therefore
-leave speed on the table on every backend but the one they were picked on.
+The paged Pallas kernels expose tunable tile knobs — ``fan`` (for paged
+decode and verify, the pages one in-kernel chunk copies and reduces, with
+``None`` picking about 256 tokens' worth from the shapes; for prefill, the
+pages one grid step fetches), ``q_blk`` (prefill query sub-block rows per
+program) and the dense decode kernel's ``kv_blk`` (KV rows per program) —
+whose best values depend on the executing backend: the interpret oracle
+pays per-step Python overhead, a real MXU wants tiles near its native
+shape, and the CPU ``ref`` path ignores them entirely.  Hand-picked
+defaults therefore leave speed on the table on every backend but the one
+they were picked on.
 
 ``sweep()`` times each candidate config on representative serving shapes
 (median of repeats, executed on the live backend) and records the winners
@@ -52,20 +54,22 @@ TUNED_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tuned")
 DTYPE_KEYS = ("fp32", "int8", "fp8")
 
 # candidate values per kernel — every candidate is legal on every shape
-# (the kernels clamp to divisors), so a tuned file can never break a call
+# (the kernels clamp to the table width, prefill to its divisors), so a
+# tuned file can never break a call
 SPACE: Dict[str, Dict[str, Tuple[Any, ...]]] = {
     "decode_dense": {"kv_blk": (128, 256, 512)},
-    "paged_decode": {"fan": (1, 2, 4, 8)},
-    "paged_verify": {"fan": (1, 2, 4, 8)},
+    "paged_decode": {"fan": (None, 8, 16, 32, 64)},
+    "paged_verify": {"fan": (None, 8, 16, 32, 64)},
     "paged_prefill": {"q_blk": (4, 8, 16), "fan": (1, 2, 4)},
 }
 
 # the hand-picked pre-autotune values (also the REPRO_KERNEL_TUNED=off
-# fallback and the baseline `sweep` reports its speedup against)
+# fallback and the baseline `sweep` reports its speedup against); a None
+# ``fan`` is the paged decode kernel's own pick from the shapes
 DEFAULTS: Dict[str, Dict[str, Any]] = {
     "decode_dense": {"kv_blk": 256},
-    "paged_decode": {"fan": 1},
-    "paged_verify": {"fan": 1},
+    "paged_decode": {"fan": None},
+    "paged_verify": {"fan": None},
     "paged_prefill": {"q_blk": 8, "fan": 1},
 }
 
@@ -126,11 +130,11 @@ def lookup(kernel: str, dtype: str, *, interpret: bool = False
 # the sweep: representative serving shapes, timed on the live backend
 # ---------------------------------------------------------------------------
 
-def _bench_operands(dtype: str, seed: int = 0):
+def _bench_operands(dtype: str, seed: int = 0, s: int = 64):
     """One representative paged serving shape (mirrors the proxy engine:
-    GQA 4:2 heads, hd 32, 8-slot pages, 64-token caches over 8 logical
-    blocks, ragged lengths)."""
-    s, h, kh, hd, page, b = 64, 4, 2, 32, 8, 8
+    GQA 4:2 heads, hd 32, 8-slot pages, ``s``-token caches over ``s / 8``
+    logical blocks, ragged lengths)."""
+    h, kh, hd, page, b = 4, 2, 32, 8, 8
     n_logical = s // page
     n_pages = 1 + b * n_logical
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -144,9 +148,16 @@ def _bench_operands(dtype: str, seed: int = 0):
     return pools, bt, clen, (h, kh, hd, page), k3
 
 
+PAGED_DECODE_ROW_TOKENS = 136
+
+
 def _kernel_call(kernel: str, dtype: str, cfg: Dict[str, Any]):
-    """Build a zero-arg thunk running one kernel invocation with ``cfg``."""
-    pools, bt, clen, (h, kh, hd, page), kq = _bench_operands(dtype)
+    """Build a zero-arg thunk running one kernel invocation with ``cfg``.
+    Paged decode and verify get 136-token rows (17 pages, which no chunk
+    size divides), so the chunk candidates walk different schedules."""
+    s = PAGED_DECODE_ROW_TOKENS if kernel in ("paged_decode", "paged_verify") \
+        else 64
+    pools, bt, clen, (h, kh, hd, page), kq = _bench_operands(dtype, s=s)
     kp = pools["k"].transpose(0, 2, 1, 3)
     vp = pools["v"].transpose(0, 2, 1, 3)
     scales = {}
@@ -156,29 +167,35 @@ def _kernel_call(kernel: str, dtype: str, cfg: Dict[str, Any]):
     b = bt.shape[0]
     group = h // kh
     interp = jax.default_backend() != "tpu"
+
+    def jitted(fn, *args, paged=True, **static):
+        # compiled once in the warm-up call, so the timed calls run the
+        # kernel, not its tracing; scales ride the paged kernels only
+        f = jax.jit(functools.partial(fn, interpret=interp, **static))
+        kw = scales if paged else {}
+        return lambda: f(*args, **kw)
+
     if kernel == "decode_dense":
         s = 512
         clen_d = jnp.minimum(clen * 8, s)
         kd = jax.random.normal(kq, (b, kh, s, hd), jnp.float32)
         q = jax.random.normal(kq, (b, kh, group, hd), jnp.float32)
-        return lambda: decode_attention_pallas(
-            q, kd, kd, clen_d, kv_blk=cfg["kv_blk"], interpret=interp)
+        return jitted(decode_attention_pallas, q, kd, kd, clen_d,
+                      paged=False, kv_blk=cfg["kv_blk"])
     if kernel == "paged_decode":
         q = jax.random.normal(kq, (b, kh, group, hd), jnp.float32)
-        return lambda: paged_decode_attention_pallas(
-            q, kp, vp, bt, clen, fan=cfg["fan"], **scales, interpret=interp)
+        return jitted(paged_decode_attention_pallas, q, kp, vp, bt, clen,
+                      fan=cfg["fan"])
     if kernel == "paged_verify":
         t = 3                                   # γ+1 verify chunk
         q = jax.random.normal(kq, (b, kh, t * group, hd), jnp.float32)
-        return lambda: paged_decode_attention_pallas(
-            q, kp, vp, bt, clen, q_len=t, fan=cfg["fan"], **scales,
-            interpret=interp)
+        return jitted(paged_decode_attention_pallas, q, kp, vp, bt, clen,
+                      q_len=t, fan=cfg["fan"])
     if kernel == "paged_prefill":
         c = 16                                  # prefill chunk
         q = jax.random.normal(kq, (b, kh, c * group, hd), jnp.float32)
-        return lambda: paged_prefill_attention_pallas(
-            q, kp, vp, bt, clen, q_len=c, q_blk=cfg["q_blk"],
-            fan=cfg["fan"], **scales, interpret=interp)
+        return jitted(paged_prefill_attention_pallas, q, kp, vp, bt, clen,
+                      q_len=c, q_blk=cfg["q_blk"], fan=cfg["fan"])
     raise ValueError(f"unknown kernel {kernel!r}")
 
 
@@ -235,7 +252,8 @@ def sweep(kernels=None, dtypes=DTYPE_KEYS, repeats: int = 3,
     return {
         "backend": backend_key(interpret),
         "tool": "repro.kernels.autotune",
-        "shapes": "proxy serving: GQA 4:2, hd 32, page 8, 8x64-token rows",
+        "shapes": "proxy serving: GQA 4:2, hd 32, page 8, 8x64-token rows "
+                  "(paged decode and verify: 8x136)",
         "repeats": repeats,
         "configs": configs,
         "timings_ms": timings,

@@ -189,9 +189,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     through its block table, so shared prefix pages are fetched once per
     page, not once per sequence.  ``k_scale``/``v_scale`` (n_pages, page, K)
     f32: the pools are int8/fp8 with per-slot symmetric scales, dequanted
-    inside the kernel (see ``kernels/kv_quant.py``).  ``fan`` (page-block
-    fan-in) and ``native_dot`` (fp8 widening-dot path) default to the
-    backend's autotuned config (``kernels/autotune.py``)."""
+    inside the kernel (see ``kernels/kv_quant.py``).  ``fan`` (pages per
+    in-kernel chunk; None lets the kernel pick from the shapes) and
+    ``native_dot`` (fp8 widening-dot path) default to the backend's
+    autotuned config (``kernels/autotune.py``)."""
     kind, interp = _resolve(impl)
     cache_len = jnp.asarray(cache_len, jnp.int32)
     if kind in ("ref", "flash_structured"):
@@ -285,8 +286,9 @@ def paged_multi_decode_attention(q: jax.Array, k_pool: jax.Array,
     T = γ+1 draft positions of every row through its block table (shared
     read-only prefix pages fetched once per page, never written).
     ``k_scale``/``v_scale`` (n_pages, page, K): int8/fp8 pools, in-kernel
-    dequant (fp8 may take the native widening-dot path); ``fan`` defaults
-    to the backend's autotuned ``paged_verify`` config."""
+    dequant (fp8 may take the native widening-dot path); ``fan`` (pages
+    per in-kernel chunk) defaults to the backend's autotuned
+    ``paged_verify`` config."""
     kind, interp = _resolve(impl)
     cache_len = jnp.asarray(cache_len, jnp.int32)
     if kind in ("ref", "flash_structured"):

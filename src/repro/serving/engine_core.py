@@ -1875,6 +1875,8 @@ class EngineCore:
                 return self._step_spec()
             if rows == 0:
                 return []
+            if self.cache_impl == "paged":
+                sp.attrs["kv_pages"] = self._kv_pages()
             return self._step_plain()
 
     def _step_plain(self) -> List[Tuple[Request, np.ndarray]]:
@@ -1920,6 +1922,14 @@ class EngineCore:
             self._compile_guard.check("step")
             sp.attrs["finished"] = [r.request_id for r, _ in finished]
         return finished
+
+    def _kv_pages(self) -> int:
+        """Pages the plain step's paged decode walks, from host state: an
+        active slot at cache index i reads i + 1 positions, so
+        ceil((i + 1) / page) pages."""
+        ps = self._page_size
+        return sum(-(-(self._slot_pos(i) + 1) // ps)
+                   for i, s in enumerate(self._slots) if s.active)
 
     def _slot_pos(self, i: int) -> int:
         """A slot's current logical cache index, from the phase machine

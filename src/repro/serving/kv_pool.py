@@ -117,14 +117,21 @@ class KVPagePool:
 
     def free(self, pages: Iterable[int]) -> None:
         """Drop one reference per page; zero-ref pages return to the pool."""
-        for p in pages:
-            if p == TRASH_PAGE:
-                raise ValueError("trash page is never freed")
-            if self._ref[p] <= 0:
-                raise ValueError(f"double free of page {p}")
-            self._ref[p] -= 1
-            if self._ref[p] == 0:
-                self._free.append(p)
+        released = []
+        try:
+            for p in pages:
+                if p == TRASH_PAGE:
+                    raise ValueError("trash page is never freed")
+                if self._ref[p] <= 0:
+                    raise ValueError(f"double free of page {p}")
+                self._ref[p] -= 1
+                if self._ref[p] == 0:
+                    released.append(p)
+        finally:
+            # reversed onto the stack, so the next alloc hands them out in
+            # the order they were freed: a run of consecutive pages stays
+            # one, which the paged decode kernel copies in one piece
+            self._free.extend(reversed(released))
 
 
 @dataclasses.dataclass

@@ -735,3 +735,70 @@ def test_paged_decode_quantized_zero_scale_rows(kv_dtype):
                                      impl="pallas_interpret")
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_array_equal(np.asarray(got), 0.0)
+
+
+@pytest.mark.kernel_parity
+@pytest.mark.parametrize("pool,fan", [("bf16", 32), ("bf16", 8),
+                                      ("int8", 8), ("fp8", 8)])
+@pytest.mark.parametrize("q_len,window", [(1, 0), (1, 20), (3, 0), (3, 20)])
+def test_paged_decode_chunk_walk_parity(pool, fan, q_len, window):
+    """The chunked page walk (interpret mode) on a prime table width, 37
+    blocks, that no chunk size divides: chunks of 32 pages (256 tokens) and
+    of 8 leave partial last chunks.  One batch holds the lengths 0, 1,
+    page - 1, page, either side of and on both chunk boundaries (64 and 256
+    tokens), and the full width; q_len 3 puts a row shorter than the chunk
+    (fully masked early tokens) beside them.  Even rows map scattered
+    pages (a copy per page); odd rows map two runs of consecutive pages
+    that break at block 20, so a chunk is copied whole, or page by page
+    where it spans the break or runs past the row's end."""
+    page, n_logical, h, kh, hd = 8, 37, 4, 2, 32
+    clen = jnp.asarray([0, 1, page - 1, page, 63, 64, 65, 255, 256, 257,
+                        n_logical * page], jnp.int32)
+    b = clen.shape[0]
+    n_pages = 1 + 2 + b * n_logical
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(11), 3)
+    kp = _rand(k1, (n_pages, page, kh, hd), jnp.float32)
+    vp = _rand(k2, (n_pages, page, kh, hd), jnp.float32)
+    bt = _block_tables(np.random.RandomState(5), b, n_logical, n_pages,
+                       n_shared=2)
+    runs = np.concatenate([np.arange(20) + n_pages - 20,
+                           np.arange(n_logical - 20) + 3])
+    bt[1::2] = runs
+    bt = jnp.asarray(bt)
+    if pool == "bf16":
+        st = kv_quant.get_strategy("exact")
+        pools = {"k": kp.astype(jnp.bfloat16), "v": vp.astype(jnp.bfloat16)}
+    else:
+        st = kv_quant.get_strategy(pool)
+        pools = st.make_pools(kp, vp)
+    q = _rand(k3, (b, q_len, h, hd), jnp.float32)
+    which = "multi" if q_len > 1 else "decode"
+    fn = (ops.paged_multi_decode_attention if q_len > 1
+          else ops.paged_decode_attention)
+    qq = q if q_len > 1 else q[:, 0]
+    got = fn(qq, pools["k"], pools["v"], bt, clen, window=window, fan=fan,
+             impl="pallas_interpret", **st.scale_kwargs(pools))
+    want = st.oracle(which, qq, pools, bt, clen, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=st.tol_self, atol=st.tol_self)
+    assert np.all(np.asarray(got)[0] == 0)      # empty row → exact zeros
+
+
+def test_paged_decode_chunk_pages_follow_the_shapes():
+    """``fan=None`` picks CHUNK_TOKENS worth of pages from the page size,
+    shrinks to the VMEM budget where pages are wide, and never exceeds
+    the table; an explicit ``fan`` only meets the table bound."""
+    from repro.kernels import decode_attention as DA
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert DA.chunk_pages(None, 257, 2, 8, 128, bf16, False) == 64
+    assert DA.chunk_pages(None, 257, 2, 16, 128, bf16, False) == 32
+    quant = DA.chunk_pages(None, 257, 4, 8, 128, jnp.int8, True)
+    assert quant == 32 and 2 * quant * DA._page_vmem_bytes(
+        4, 8, 128, jnp.int8, True) == DA.CHUNK_VMEM_BYTES
+    assert DA.chunk_pages(None, 10, 2, 8, 128, bf16, False) == 10
+    wide = DA.chunk_pages(None, 257, 8, 8, 512, f32, False)
+    assert wide < 64
+    assert 2 * wide * DA._page_vmem_bytes(8, 8, 512, f32, False) \
+        <= DA.CHUNK_VMEM_BYTES
+    assert DA.chunk_pages(5, 257, 2, 8, 128, bf16, False) == 5
+    assert DA.chunk_pages(64, 37, 2, 8, 128, bf16, False) == 37

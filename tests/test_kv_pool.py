@@ -32,6 +32,26 @@ from repro.serving.kv_pool import PrefixCache, TRASH_PAGE, page_nbytes
 # allocator invariants
 # ---------------------------------------------------------------------------
 
+def test_pool_hands_a_freed_run_out_again_in_order():
+    """Pages come off the free list in the order they were freed, so a
+    slot's consecutive private pages (or a scene's prefix pages) are
+    consecutive again when reused: the paged decode kernel copies such a
+    run in one piece."""
+    pool = KVPagePool(n_pages=12, page_size=4)
+    a = pool.alloc(4)
+    b = pool.alloc(3)
+    assert a == [1, 2, 3, 4] and b == [5, 6, 7]
+    pool.free(a)
+    pool.free(b)
+    assert pool.alloc(3) == b
+    assert pool.alloc(4) == a
+    # a failed free still returns what it released before the fault
+    pool.free(a[:2])
+    with pytest.raises(ValueError):
+        pool.free([a[2], a[0]])
+    assert sorted(pool.alloc(3)) == a[:3]
+
+
 def test_pool_alloc_free_roundtrip():
     pool = KVPagePool(n_pages=9, page_size=4)
     assert pool.free_pages == 8                 # page 0 reserved as trash

@@ -6,6 +6,7 @@ import threading
 import time
 
 import jax
+import numpy as np
 import pytest
 
 from repro.configs.spaceverse_pair import proxy_pair
@@ -147,6 +148,36 @@ def _one_round(core, images):
     return spans, reqs, rows, core.stats["prefix_misses"] - m0
 
 
+def _walked_pages(core):
+    """Pages the next step's paged decode reads, from the device's own
+    cache indices: an active row at index i reads i + 1 positions."""
+    ps = core._page_size
+    return int(sum(-(-(int(i) + 1) // ps)
+                   for i, s in zip(np.asarray(core._slot_index), core._slots)
+                   if s.active))
+
+
+def test_step_kv_pages_counts_the_pages_each_row_walks(sat_system):
+    """``engine.step``'s ``kv_pages`` (host state only) equals the pages
+    the step's decode walks by the device's cache indices, summed over the
+    active rows, as rows join, grow and finish."""
+    core = _core(sat_system)
+    images = sat_system[3]["images"]
+    core.admit_many([Request(task=t, image=images[0], prompt=0)
+                     for t in ("det", "vqa")])
+    seen = []
+    for i in range(6):
+        if i == 2:
+            core.admit_many([Request(task="det", image=images[1], prompt=0)])
+        want = _walked_pages(core)
+        t = time.perf_counter()
+        core.step()
+        (step,) = obs.TRACER.spans("engine.step", since=t)
+        assert step.attrs["kv_pages"] == want
+        seen.append(want)
+    assert len(set(seen)) > 1                   # rows joined and grew
+
+
 def test_paged_admit_and_step_spans(sat_system):
     core = _core(sat_system)
     data = sat_system[3]
@@ -163,7 +194,11 @@ def test_paged_admit_and_step_spans(sat_system):
     assert a["active_after"] == 4
     assert spans[2].attrs["scenes"] == 2
     assert spans[2].attrs["image_bytes"] == 2 * data["images"][0].nbytes
-    assert step.attrs == {"slots": 4, "rows": rows}
+    # every row was admitted just now: its step reads regions + prompt +
+    # the token it writes
+    fresh = -(-(core.ac.n_regions + 2) // core._page_size)
+    assert step.attrs == {"slots": 4, "rows": rows,
+                          "kv_pages": rows * fresh}
     # the admission uploaded the block table; the step, the active mask
     assert spans[len(ADMIT) + 1].attrs["what"] == ("active",)
     # the vqa answers (one token) finish in this step, the det ones do not

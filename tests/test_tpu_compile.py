@@ -96,6 +96,22 @@ def test_paged_kernels_compile(one_chip, kernel, q_len, dtype, heads):
     _compile(fn, *_paged_operands(one_chip, heads, dtype, q_len))
 
 
+def test_paged_decode_compiles_at_the_benchmark_shape(one_chip):
+    """The serving benchmark's decode step: 32 slots of 257 pages (1024
+    regions + prompt + a 1024-token answer), a pool of 1 + 32·257 + 64
+    cached scenes · 128 pages, bf16, Qwen2-VL-2B heads, the default chunk
+    (a 257-block table that no chunk size divides)."""
+    kh, group = HEADS["2b"]
+    slots, pages = 32, -(-(N_REGIONS + 1 + N_REGIONS) // PAGE)
+    n_pages = 1 + slots * pages + 64 * (N_REGIONS // PAGE)
+    assert (pages, n_pages) == (257, 16417)
+    pool = _sds(one_chip, (n_pages, kh, PAGE, HD), jnp.bfloat16)
+    _compile(paged_decode_attention_pallas,
+             _sds(one_chip, (slots, kh, group, HD), jnp.bfloat16), pool,
+             pool, _sds(one_chip, (slots, pages), jnp.int32),
+             _sds(one_chip, (slots,), jnp.int32))
+
+
 @pytest.mark.parametrize("sq", [N_REGIONS, N_REGIONS + 1])
 def test_flash_prefill_compiles(one_chip, sq):
     """The scene prefix (1024 regions) and the dense batch-path prefill
