@@ -349,6 +349,16 @@ class EngineCore:
             params = jax.device_put(params, SH.named(self.mesh,
                                                      self._p_specs))
         self._tp_plan = plan
+        #: what one token's pass through the model all-reduces on each
+        #: device: every layer fires ``tp_attn_all_reduce`` where attention
+        #: is head-sharded and ``tp_mlp_all_reduce`` where the MLP is
+        #: column-sharded, each over a (tokens, d_model) activation; the
+        #: ``engine.step`` / ``engine.admit`` spans carry the call's total
+        self._tp = plan.tp if plan is not None else 1
+        self._allreduce_per_token = (
+            (plan.attn + plan.mlp) * cfg.num_layers * cfg.d_model
+            * jnp.dtype(cfg.dtype).itemsize if plan is not None else 0)
+        self._allreduce_bytes = 0
         mesh = self.mesh
         # Every jitted family takes the weights as an OPERAND, never as a
         # closure: jit embeds closed-over arrays into the program as
@@ -1072,16 +1082,20 @@ class EngineCore:
         if self._slot_cache is None:
             cfg = self.tier.cfg
             if self.cache_impl == "paged":
-                self._slot_cache = T.init_paged_cache(
-                    cfg, self.cfg.slots, self._n_pages, self._page_size,
-                    kv_dtype=self.cfg.kv_dtype)
-                if self.mesh is not None:
-                    # commit the pool to its head-sharded layout up front;
-                    # every sharded step keeps it there (logits/index stay
-                    # uncommitted and auto-replicate)
-                    self._slot_cache = jax.device_put(
-                        self._slot_cache,
-                        SH.named(self.mesh, self._cache_specs))
+                pool = functools.partial(
+                    T.init_paged_cache, cfg, self.cfg.slots, self._n_pages,
+                    self._page_size, kv_dtype=self.cfg.kv_dtype)
+                if self.mesh is None:
+                    self._slot_cache = pool()
+                else:
+                    # made straight into its head-sharded layout, each
+                    # device zeroing only its own KV heads: the whole pool
+                    # need not fit one device beside its weight shards (the
+                    # 7B's K and V pools are 11.3 GB); every sharded step
+                    # keeps it there (logits/index auto-replicate)
+                    self._slot_cache = jax.jit(
+                        pool, out_shardings=SH.named(
+                            self.mesh, self._cache_specs))()
             else:
                 self._slot_cache = T.init_cache(cfg, self.cfg.slots,
                                                 self._slot_max_len)
@@ -1297,12 +1311,20 @@ class EngineCore:
         k = len(requests)
         with obs.span("engine.admit", requests=k,
                       bucket=self._admit_pad(k, self.cfg.slots),
-                      request_ids=[r.request_id for r in requests]) as sp:
+                      request_ids=[r.request_id for r in requests],
+                      tp=self._tp) as sp:
             m0 = self.stats["prefix_misses"]
+            self._allreduce_bytes = 0
             out = self._admit_many(requests)
             sp.attrs["misses"] = self.stats["prefix_misses"] - m0
             sp.attrs["active_after"] = self.active_count()
+            sp.attrs["allreduce_bytes"] = self._allreduce_bytes
         return out
+
+    def _note_allreduce(self, tokens: int) -> None:
+        """Count the tensor-parallel all-reduce bytes of a dispatched call
+        that runs ``tokens`` tokens through the model (0 off a mesh)."""
+        self._allreduce_bytes += tokens * self._allreduce_per_token
 
     def _admit_many(self, requests: List[Request]) -> List[int]:
         t_admit = time.perf_counter()      # arrival at the engine: TTFT
@@ -1397,6 +1419,7 @@ class EngineCore:
                              + [np.asarray(miss[-1][1].image)] * (kpad - km))
             sp.attrs["image_bytes"] = stack.nbytes
             cache = self._prefill_prefix_j(self._p, jnp.asarray(stack))
+            self._note_allreduce(kpad * self.ac.n_regions)
             pages = np.full((kpad, n_shared), TRASH_PAGE, np.int32)
             allocs = []
             for i in range(km):
@@ -1474,6 +1497,8 @@ class EngineCore:
                                     jnp.asarray(admit_slots),
                                     jnp.asarray(ptoks_pad, jnp.int32),
                                     prefix_state)
+            # the prompt rows ride one decode step over the whole table
+            self._note_allreduce(self.cfg.slots)
         self._note_prefill("prompt", k)        # one prompt token per request
         if self.cfg.spec_gamma:
             # the drafter mirrors the slot table on its own dense cache: one
@@ -1864,20 +1889,28 @@ class EngineCore:
         slots freed by the previous step refill before advancing.
 
         Every flavour runs inside one ``engine.step`` span (``obs``)."""
-        with obs.span("engine.step", slots=self.cfg.slots) as sp:
-            if self._admq is not None:
-                self._pump_queue()
-            sp.attrs["rows"] = rows = self.active_count()
-            if self.cfg.prefill_chunk and any(
-                    s.active and s.phase != "decode" for s in self._slots):
-                return self._step_chunked()
-            if self.cfg.spec_gamma:
-                return self._step_spec()
-            if rows == 0:
-                return []
-            if self.cache_impl == "paged":
-                sp.attrs["kv_pages"] = self._kv_pages()
-            return self._step_plain()
+        with obs.span("engine.step", slots=self.cfg.slots,
+                      tp=self._tp) as sp:
+            self._allreduce_bytes = 0
+            try:
+                return self._step(sp)
+            finally:
+                sp.attrs["allreduce_bytes"] = self._allreduce_bytes
+
+    def _step(self, sp) -> List[Tuple[Request, np.ndarray]]:
+        if self._admq is not None:
+            self._pump_queue()
+        sp.attrs["rows"] = rows = self.active_count()
+        if self.cfg.prefill_chunk and any(
+                s.active and s.phase != "decode" for s in self._slots):
+            return self._step_chunked()
+        if self.cfg.spec_gamma:
+            return self._step_spec()
+        if rows == 0:
+            return []
+        if self.cache_impl == "paged":
+            sp.attrs["kv_pages"] = self._kv_pages()
+        return self._step_plain()
 
     def _step_plain(self) -> List[Tuple[Request, np.ndarray]]:
         """One token for every active slot: re-upload what admission or
@@ -1901,6 +1934,7 @@ class EngineCore:
                                   self._slot_index, self._active_dev,
                                   *self._step_args(),
                                   answer_vocab=self.cfg.answer_vocab)
+            self._note_allreduce(self.cfg.slots)
         with obs.span("engine.step.fetch"):
             # spacelint: disable=SL001 (the single deliberate per-step fetch: committed tokens must reach the host-side scheduler)
             toks_np = np.asarray(toks)
@@ -2028,6 +2062,7 @@ class EngineCore:
                 jnp.asarray(srow), jnp.asarray(tokens), jnp.asarray(pos),
                 jnp.asarray(patch_mask), jnp.asarray(use_argmax),
                 answer_vocab=self.cfg.answer_vocab)
+        self._note_allreduce(self._token_budget)
         # spacelint: disable=SL001 (the single deliberate per-step fetch: committed tokens must reach the host-side phase machine)
         toks_np = np.asarray(tok)
         probs_np = None
@@ -2196,6 +2231,9 @@ class EngineCore:
                     self._bb, self._dp, *args, self._draft_cache,
                     jnp.asarray(pend),
                     jnp.asarray(plen), answer_vocab=self.cfg.answer_vocab)
+        # the verifier scores a γ+1-token chunk per row (the drafter's own
+        # calls are not counted)
+        self._note_allreduce(self.cfg.slots * (self.cfg.spec_gamma + 1))
         # spacelint: disable=SL001 (the single deliberate per-step fetch: the verified chunk must reach the host-side scheduler)
         chunk_np = np.asarray(chunk)
         n_np = np.asarray(n_commit)  # spacelint: disable=SL001 (accept counts ride the same per-step fetch)

@@ -738,10 +738,15 @@ def test_paged_decode_quantized_zero_scale_rows(kv_dtype):
 
 
 @pytest.mark.kernel_parity
-@pytest.mark.parametrize("pool,fan", [("bf16", 32), ("bf16", 8),
-                                      ("int8", 8), ("fp8", 8)])
+@pytest.mark.parametrize("pool,fan,h,kh", [
+    pytest.param("bf16", 32, 4, 2, id="bf16-32"),
+    pytest.param("bf16", 8, 4, 2, id="bf16-8"),
+    pytest.param("int8", 8, 4, 2, id="int8-8"),
+    pytest.param("fp8", 8, 4, 2, id="fp8-8"),
+    # one device's share of Qwen2-VL-7B on four: 1 KV head, group 7
+    pytest.param("bf16", 32, 7, 1, id="bf16-32-kv1-group7")])
 @pytest.mark.parametrize("q_len,window", [(1, 0), (1, 20), (3, 0), (3, 20)])
-def test_paged_decode_chunk_walk_parity(pool, fan, q_len, window):
+def test_paged_decode_chunk_walk_parity(pool, fan, h, kh, q_len, window):
     """The chunked page walk (interpret mode) on a prime table width, 37
     blocks, that no chunk size divides: chunks of 32 pages (256 tokens) and
     of 8 leave partial last chunks.  One batch holds the lengths 0, 1,
@@ -751,7 +756,7 @@ def test_paged_decode_chunk_walk_parity(pool, fan, q_len, window):
     pages (a copy per page); odd rows map two runs of consecutive pages
     that break at block 20, so a chunk is copied whole, or page by page
     where it spans the break or runs past the row's end."""
-    page, n_logical, h, kh, hd = 8, 37, 4, 2, 32
+    page, n_logical, hd = 8, 37, 32
     clen = jnp.asarray([0, 1, page - 1, page, 63, 64, 65, 255, 256, 257,
                         n_logical * page], jnp.int32)
     b = clen.shape[0]

@@ -192,13 +192,15 @@ def test_paged_admit_and_step_spans(sat_system):
     assert a["request_ids"] == [r.request_id for r in reqs]
     assert a["misses"] == misses == 2
     assert a["active_after"] == 4
+    assert a["tp"] == 1 and a["allreduce_bytes"] == 0      # one device
     assert spans[2].attrs["scenes"] == 2
     assert spans[2].attrs["image_bytes"] == 2 * data["images"][0].nbytes
     # every row was admitted just now: its step reads regions + prompt +
     # the token it writes
     fresh = -(-(core.ac.n_regions + 2) // core._page_size)
     assert step.attrs == {"slots": 4, "rows": rows,
-                          "kv_pages": rows * fresh}
+                          "kv_pages": rows * fresh, "tp": 1,
+                          "allreduce_bytes": 0}
     # the admission uploaded the block table; the step, the active mask
     assert spans[len(ADMIT) + 1].attrs["what"] == ("active",)
     # the vqa answers (one token) finish in this step, the det ones do not
@@ -216,6 +218,49 @@ def test_paged_admit_and_step_spans(sat_system):
     core.step()
     up = obs.TRACER.spans("engine.step.upload", since=t)
     assert [s.attrs["what"] for s in up] == [("active", "block_table")]
+
+
+#: Qwen2-VL-7B's head ratios at a tiny width: 28 query and 4 KV heads, so
+#: a (1, 4) mesh splits attention (one KV head a device) and the MLP
+GS_TINY = dict(num_layers=2, d_model=64, num_heads=28, num_kv_heads=4,
+               d_ff=128, head_dim=16, mrope_sections=(2, 3, 3),
+               vocab_size=256, num_patches=16, tie_embeddings=False)
+
+
+@pytest.mark.parametrize("model,split", [("gs-ratios", (True, True)),
+                                         ("sat-proxy", (False, True))])
+def test_mesh_spans_carry_tp_and_allreduce_bytes(sat_system, make_mesh,
+                                                 model, split):
+    """On a (1, 4) mesh ``engine.admit`` and ``engine.step`` carry the
+    model-axis size and the bytes each device all-reduces in the call:
+    (attention hook if attention is head-sharded, plus MLP hook if the MLP
+    is) × layers × tokens the call runs × d_model × activation bytes.  The
+    7B's ratios shard both; the satellite proxy's 2 KV heads do not divide
+    by 4, so only its MLP all-reduces."""
+    import dataclasses
+    from repro.configs.spaceverse_pair import GS_CONFIG
+    from repro.distributed import sharding as SH
+    _, sat_cfg, ac, data = sat_system
+    cfg = (dataclasses.replace(GS_CONFIG, **GS_TINY) if model == "gs-ratios"
+           else sat_cfg)
+    mesh = make_mesh(1, 4)
+    plan = SH.tp_serving_plan(cfg, mesh)
+    assert (plan.attn, plan.mlp) == split
+    params = EO.init_adapter(jax.random.PRNGKey(0), cfg, ac)
+    core = EngineCore(TierModel(params, cfg), ac,
+                      EngineCoreConfig(slots=4, answer_vocab=9, mesh=mesh))
+    spans, _, _, misses = _one_round(core, data["images"][:2])
+    admit = next(s for s in spans if s.name == "engine.admit")
+    step = next(s for s in spans if s.name == "engine.step")
+    per_token = ((plan.attn + plan.mlp) * cfg.num_layers * cfg.d_model
+                 * np.dtype(cfg.dtype).itemsize)
+    assert misses == 2
+    assert admit.attrs["tp"] == step.attrs["tp"] == 4
+    # two scenes' region prefill, then the prompt rows in one step over
+    # the whole 4-slot table
+    assert admit.attrs["allreduce_bytes"] == per_token * (
+        2 * ac.n_regions + 4)
+    assert step.attrs["allreduce_bytes"] == per_token * 4
 
 
 def test_dense_admit_and_step_spans(sat_system):

@@ -96,15 +96,21 @@ def test_paged_kernels_compile(one_chip, kernel, q_len, dtype, heads):
     _compile(fn, *_paged_operands(one_chip, heads, dtype, q_len))
 
 
-def test_paged_decode_compiles_at_the_benchmark_shape(one_chip):
-    """The serving benchmark's decode step: 32 slots of 257 pages (1024
-    regions + prompt + a 1024-token answer), a pool of 1 + 32·257 + 64
-    cached scenes · 128 pages, bf16, Qwen2-VL-2B heads, the default chunk
-    (a 257-block table that no chunk size divides)."""
-    kh, group = HEADS["2b"]
-    slots, pages = 32, -(-(N_REGIONS + 1 + N_REGIONS) // PAGE)
+@pytest.mark.parametrize("slots,kh,group,want_pages", [
+    pytest.param(32, 2, 6, 16417, id="2b"),
+    # one chip's share of Qwen2-VL-7B on a (1, 4) mesh: 1 of 4 KV heads
+    pytest.param(64, 1, 7, 24641, id="7b-tp4")])
+def test_paged_decode_compiles_at_the_benchmark_shape(one_chip, slots, kh,
+                                                      group, want_pages):
+    """The serving benchmark's decode steps: slots of 257 pages (1024
+    regions + prompt + a 1024-token answer), a pool of 1 + slots·257 + 64
+    cached scenes · 128 pages, bf16, the default chunk (a 257-block table
+    that no chunk size divides).  The 2B runs 32 slots of 2 KV heads
+    (group 6) on one chip; each of the 7B's four chips runs 64 slots of
+    its one KV head (group 7)."""
+    pages = -(-(N_REGIONS + 1 + N_REGIONS) // PAGE)
     n_pages = 1 + slots * pages + 64 * (N_REGIONS // PAGE)
-    assert (pages, n_pages) == (257, 16417)
+    assert (pages, n_pages) == (257, want_pages)
     pool = _sds(one_chip, (n_pages, kh, PAGE, HD), jnp.bfloat16)
     _compile(paged_decode_attention_pallas,
              _sds(one_chip, (slots, kh, group, HD), jnp.bfloat16), pool,
